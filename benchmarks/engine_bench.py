@@ -21,12 +21,15 @@ Times, at |V| in {1k, 10k} (CPU-friendly sizes; same code path on TPU):
     grid.CALL_COUNTS) and timed (each subset must beat the all-metrics
     program).
 
-  * **mesh-sharded batched** (|V|=1k, B=32, 4 forced host devices, in a
-    clean subprocess so the forced-device view cannot perturb the
-    single-host timings): ``repro.distributed.batched``'s batch-axis
-    sharding vs a per-layout ``evaluate_sharded`` loop (>= 1.5x gate,
-    plus bit-identical integer parity with the single-host batched
-    program) — the ``sharded_batched`` record.
+  * **mesh-sharded batched** (|V|=1k, B=32, 4 devices):
+    ``repro.distributed.batched``'s batch-axis sharding vs a per-layout
+    ``evaluate_sharded`` loop (>= 1.5x gate, plus bit-identical integer
+    parity with the single-host batched program) — the
+    ``sharded_batched`` record.  On an accelerator it runs in this
+    process on a sub-mesh of ``jax.devices()[:4]`` (a chip belongs to one
+    process); on the CPU backend it runs in a clean subprocess with 4
+    forced host devices, so the forced-device view cannot perturb the
+    single-host timings.
 
 ``--config '{"n_strips": 128, ...}'`` overrides the base EvalConfig.
 ``--smoke`` runs only the subset-pruning sections (single-host AND
@@ -35,7 +38,7 @@ was built) — CI uses it so metric-subset pruning regressions fail fast.
 ``--devices N`` forces N host devices
 (``XLA_FLAGS=--xla_force_host_platform_device_count``) before jax
 initializes; ``--sharded-only`` prints just the sharded-batched record
-(the subprocess leg of the full run).
+(the CPU subprocess leg of the full run).
 
 Writes BENCH_engine.json next to this file (the perf trajectory record).
 
@@ -90,6 +93,8 @@ from repro.core.crossing import count_crossings_enhanced  # noqa: E402
 from repro.core.crossing_angle import crossing_angle_enhanced  # noqa: E402
 from repro.core.edge_length import edge_length_variation  # noqa: E402
 from repro.core.keys import EvalConfig  # noqa: E402
+from repro.graphs.layouts import lattice_graph  # noqa: E402
+from repro.launch.compile_cache import use_compile_cache  # noqa: E402
 from repro.core.min_angle import minimum_angle  # noqa: E402
 from repro.core.occlusion import count_occlusions_enhanced  # noqa: E402
 BATCH = 32
@@ -103,31 +108,8 @@ SUBSETS = {
 
 
 def make_graph(n_v, seed=0, frac_long=0.02):
-    """Layout-local graph: jittered lattice positions, lattice-neighbour
-    edges plus a sprinkle of long-range ones.
-
-    This is the enhanced algorithms' target regime (a mostly-readable
-    layout, as produced inside an optimization loop): short edges span few
-    strips, so per-strip capacities — and the O(cap^2 * strips) sweep —
-    stay proportionate.  A uniformly-random edge set would make every
-    edge span ~half the strips and blow the capacity up by ~100x, which
-    benchmarks the degenerate worst case rather than the workload.
-    """
-    rng = np.random.default_rng(seed)
-    side = int(np.ceil(np.sqrt(n_v)))
-    iy, ix = np.divmod(np.arange(n_v), side)
-    pos = np.stack([ix, iy], axis=1) * (100.0 / side)
-    pos = (pos + rng.normal(0, 0.15 * 100.0 / side,
-                            size=pos.shape)).astype(np.float32)
-    right = np.stack([np.arange(n_v), np.arange(n_v) + 1], axis=1)
-    right = right[(right[:, 1] < n_v) & (ix[: right.shape[0]] + 1 < side)]
-    down = np.stack([np.arange(n_v), np.arange(n_v) + side], axis=1)
-    down = down[down[:, 1] < n_v]
-    edges = np.concatenate([right, down])
-    n_long = int(frac_long * edges.shape[0])
-    long_e = rng.integers(0, n_v, size=(2 * n_long, 2))
-    long_e = long_e[long_e[:, 0] != long_e[:, 1]][:n_long]
-    edges = np.concatenate([edges, long_e]).astype(np.int32)
+    """:func:`repro.graphs.layouts.lattice_graph` as device arrays."""
+    pos, edges = lattice_graph(n_v, seed=seed, frac_long=frac_long)
     return jnp.asarray(pos), jnp.asarray(edges)
 
 
@@ -230,7 +212,8 @@ def bench_size(n_v, n_strips, *, batch=True):
 
 
 def bench_sharded_batched(base: EvalConfig, n_v: int = 1000,
-                          batch: int = BATCH, repeats: int = 2):
+                          batch: int = BATCH, repeats: int = 2,
+                          devices=None):
     """Mesh-sharded batched evaluation vs per-layout ``evaluate_sharded``
     looping — the composition the ISSUE-5 acceptance gate times.
 
@@ -239,12 +222,12 @@ def bench_sharded_batched(base: EvalConfig, n_v: int = 1000,
     per-metric host syncs) — measured on a few candidates and
     extrapolated like the unfused loop baseline.  The sharded-batched
     path shards the batch axis of ONE natively batched dispatch over
-    the same mesh; integer parity with the single-host batched program
-    is asserted as part of the record.
+    the same mesh (over ``devices``, default all); integer parity with
+    the single-host batched program is asserted as part of the record.
     """
     from repro.distributed.batched import evaluate_layouts_sharded
-    from repro.distributed.compat import make_mesh
     from repro.distributed.gridded import evaluate_sharded
+    from repro.launch.elastic import serving_mesh
 
     pos, edges = make_graph(n_v)
     cfg = dataclasses.replace(base, backend="distributed")
@@ -253,8 +236,8 @@ def bench_sharded_batched(base: EvalConfig, n_v: int = 1000,
     b = np.stack([np.asarray(pos) +
                   rng.normal(0, sigma, size=pos.shape).astype(np.float32)
                   for _ in range(batch)])
-    ndev = len(jax.devices())
-    mesh = make_mesh((ndev,), ("batch",))
+    mesh = serving_mesh("batch", devices=devices)
+    ndev = mesh.size
     plan = plan_readability(b, edges, **cfg.plan_kwargs())
     bj = jnp.asarray(b)
 
@@ -301,14 +284,14 @@ def smoke_sharded_batched(base: EvalConfig, n_v: int = 300) -> bool:
     config traces zero cell builds, an occlusion-only config zero strip
     builds/sweeps, *per shard body*)."""
     from repro.distributed.batched import evaluate_layouts_sharded
-    from repro.distributed.compat import make_mesh
+    from repro.launch.elastic import serving_mesh
 
     pos, edges = make_graph(n_v)
     rng = np.random.default_rng(1)
     b = jnp.asarray(np.stack(
         [np.asarray(pos) + rng.normal(0, 0.2, size=pos.shape)
          .astype(np.float32) for _ in range(4)]))
-    mesh = make_mesh((len(jax.devices()),), ("batch",))
+    mesh = serving_mesh("batch")
     ok = True
     for name, metrics in SUBSETS.items():
         if metrics is None:
@@ -383,6 +366,19 @@ def print_subsets(rec):
     print(f"  pruning: {rec['pruning']}")
 
 
+def _sharded_subprocess(n_mesh: int, config: str) -> dict:
+    sub = subprocess.run(
+        [sys.executable, os.path.abspath(__file__), "--sharded-only",
+         "--devices", str(n_mesh), "--config", config],
+        capture_output=True, text=True, timeout=1800)
+    if sub.returncode != 0:
+        print(sub.stdout + "\n" + sub.stderr)
+        sys.exit(1)
+    line = [l for l in sub.stdout.splitlines()
+            if l.startswith("SHARDED_RESULT ")][-1]
+    return json.loads(line[len("SHARDED_RESULT "):])
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--config", default="{}",
@@ -402,6 +398,7 @@ def main(argv=None):
                          "subprocess)")
     args = ap.parse_args(argv)
     base = EvalConfig(**{"n_strips": 128, **json.loads(args.config)})
+    use_compile_cache()
 
     if args.sharded_only:
         rec = bench_sharded_batched(base, n_v=1000)
@@ -451,23 +448,19 @@ def main(argv=None):
     results["metric_subsets"] = subsets
     print_subsets(subsets)
 
-    # mesh-sharded batched section: timed in a clean subprocess so the
-    # forced 4-device host view cannot perturb the single-host timings
-    # above (historical comparability), while the mesh really has 4
-    # devices (the ISSUE-5 acceptance setup)
     n_mesh = args.devices or 4
-    print(f"sharded batched @1k ({n_mesh} forced host devices) ...",
-          flush=True)
-    sub = subprocess.run(
-        [sys.executable, os.path.abspath(__file__), "--sharded-only",
-         "--devices", str(n_mesh), "--config", args.config],
-        capture_output=True, text=True, timeout=1800)
-    if sub.returncode != 0:
-        print(sub.stdout + "\n" + sub.stderr)
-        sys.exit(1)
-    line = [l for l in sub.stdout.splitlines()
-            if l.startswith("SHARDED_RESULT ")][-1]
-    sharded = json.loads(line[len("SHARDED_RESULT "):])
+    if jax.default_backend() != "cpu":
+        # a chip belongs to one process: time the mesh here, on a
+        # sub-mesh of the real devices
+        print(f"sharded batched @1k (devices[:{n_mesh}]) ...", flush=True)
+        sharded = bench_sharded_batched(base, n_v=1000,
+                                        devices=jax.devices()[:n_mesh])
+    else:
+        # CPU: a clean subprocess, so the forced n-device host view
+        # cannot perturb the single-host timings above
+        print(f"sharded batched @1k ({n_mesh} forced host devices) ...",
+              flush=True)
+        sharded = _sharded_subprocess(n_mesh, args.config)
     results["sharded_batched"] = sharded
     print(f"  devices={sharded['devices']} B={sharded['batch_size']}: "
           f"per-layout sharded loop "

@@ -7,13 +7,14 @@ This benchmark drives the graph-axis sharded engine
 at |V| in {1e4, 1e5, 1e6} across 1/2/4 forced-host devices and records,
 per (size, device count) cell:
 
-* **measured wall time** (subprocess per device count — the forced
-  device count must be set before jax initializes).  On this 1-core
-  container the forced devices timeshare one physical core, so wall
-  time CANNOT show real parallel speedup; it is recorded as the sanity
-  check that the sharded program runs at every mesh size (same
-  precedent as the seed fig4 bench and ``engine_bench``'s
-  sharded-batched record);
+* **measured wall time**.  On an accelerator every device count runs in
+  this process on a sub-mesh of ``jax.devices()[:n]`` (a chip belongs to
+  one process; the host needs 4 devices).  On the CPU backend each
+  device count runs in a subprocess with that many forced host devices
+  (the count must be set before jax initializes); those devices
+  timeshare the host's cores, so CPU wall time CANNOT show real parallel
+  speedup and is only the sanity check that the sharded program runs at
+  every mesh size;
 * the **work-based strong-scaling curve** — the max per-device share of
   the pair-comparison work under the contiguous strip/cell partition of
   :func:`repro.core.grid.plan_graph_shards`, computed host-side from
@@ -67,7 +68,12 @@ def _apply_devices_flag():
 
 _apply_devices_flag()
 
+import time  # noqa: E402
+
 import numpy as np  # noqa: E402
+
+from repro.graphs.layouts import lattice_graph  # noqa: E402
+from repro.launch.compile_cache import use_compile_cache  # noqa: E402
 
 # (size, strips, timed reps): strips grow with |V| to keep per-strip
 # capacity — and the O(cap^2 x strips) sweep — proportionate; the 1e6
@@ -87,50 +93,70 @@ def _frac_long(n_v: int) -> float:
     return min(0.02, 0.02 * 10_000 / n_v)
 
 
+def measure_cell(devices, n_v: int, n_strips: int, frac_long: float,
+                 reps: int) -> dict:
+    """Time one graph-sharded evaluation of the |V|=n_v lattice over a
+    1-D mesh of ``devices``; on one device also record the single-host
+    fused engine's integers for the parity check."""
+    import jax
+
+    from repro.core import engine
+    from repro.distributed.graph_sharded import evaluate_graph_sharded
+    from repro.launch.elastic import serving_mesh
+
+    pos, edges = lattice_graph(n_v, seed=0, frac_long=frac_long)
+    plan = engine.plan_readability(pos, edges, radius=RADIUS,
+                                   n_strips=n_strips, tier_strips=False)
+    mesh = serving_mesh("graph", devices=devices)
+
+    res = evaluate_graph_sharded(mesh, plan, pos, edges)     # compile + warm
+    jax.block_until_ready(res.node_occlusion)
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        res = evaluate_graph_sharded(mesh, plan, pos, edges)
+        jax.block_until_ready(res.node_occlusion)
+    sec = (time.perf_counter() - t0) / reps
+
+    out = dict(seconds=sec,
+               node_occlusion=int(res.node_occlusion),
+               edge_crossing=int(res.edge_crossing),
+               overflow=int(res.overflow))
+    if len(devices) == 1:
+        ref = engine.evaluate_planned(plan, pos, edges)
+        out["single_host"] = dict(node_occlusion=int(ref.node_occlusion),
+                                  edge_crossing=int(ref.edge_crossing))
+    return out
+
+
 _CHILD = r"""
-import os, sys, time, json
+import os, sys, json
 os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
                            + sys.argv[1])
 import jax
-import numpy as np
 
 sys.path.insert(0, %(bench_dir)r)
-from engine_bench import make_graph
-
-from repro.core import engine
-from repro.distributed.compat import make_mesh
-from repro.distributed.graph_sharded import evaluate_graph_sharded
+from fig4_scaling import measure_cell
 
 ndev = int(sys.argv[1])
-n_v = int(sys.argv[2])
-n_strips = int(sys.argv[3])
-frac_long = float(sys.argv[4])
-reps = int(sys.argv[5])
 assert len(jax.devices()) == ndev
-
-pos, edges = make_graph(n_v, seed=0, frac_long=frac_long)
-plan = engine.plan_readability(pos, edges, radius=%(radius)f,
-                               n_strips=n_strips, tier_strips=False)
-mesh = make_mesh((ndev,), ("graph",))
-
-res = evaluate_graph_sharded(mesh, plan, pos, edges)     # compile + warm
-jax.block_until_ready(res.node_occlusion)
-t0 = time.perf_counter()
-for _ in range(reps):
-    res = evaluate_graph_sharded(mesh, plan, pos, edges)
-    jax.block_until_ready(res.node_occlusion)
-sec = (time.perf_counter() - t0) / reps
-
-out = dict(seconds=sec,
-           node_occlusion=int(res.node_occlusion),
-           edge_crossing=int(res.edge_crossing),
-           overflow=int(res.overflow))
-if ndev == 1:
-    ref = engine.evaluate_planned(plan, pos, edges)
-    out["single_host"] = dict(node_occlusion=int(ref.node_occlusion),
-                              edge_crossing=int(ref.edge_crossing))
+out = measure_cell(jax.devices(), int(sys.argv[2]), int(sys.argv[3]),
+                   float(sys.argv[4]), int(sys.argv[5]))
 print("RESULT " + json.dumps(out))
 """
+
+
+def _cpu_cell(ndev, n_v, n_strips, reps) -> dict:
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    child = _CHILD % dict(bench_dir=os.path.dirname(os.path.abspath(__file__)))
+    res = subprocess.run(
+        [sys.executable, "-c", child, str(ndev), str(n_v),
+         str(n_strips), str(_frac_long(n_v)), str(reps)],
+        env=env, capture_output=True, text=True, timeout=3600)
+    assert res.returncode == 0, res.stdout + "\n" + res.stderr
+    line = [l for l in res.stdout.splitlines()
+            if l.startswith("RESULT ")][-1]
+    return json.loads(line[len("RESULT "):])
 
 
 def _work_model(n_v: int, n_strips: int, n_shards: int):
@@ -141,16 +167,10 @@ def _work_model(n_v: int, n_strips: int, n_shards: int):
     work: per-cell occupancy^2 plus the forward-neighbour cross
     products (the owner-cell sweep).  Returns (total, max per-device),
     whose ratio is the work-based strong-scaling speedup."""
-    import jax  # noqa: F401  (make_graph returns jax arrays)
-    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-    from engine_bench import make_graph
-
     from repro.core import engine
     from repro.core import grid as gridlib
 
-    pos, edges = make_graph(n_v, seed=0, frac_long=_frac_long(n_v))
-    pos = np.asarray(pos)
-    edges = np.asarray(edges)
+    pos, edges = lattice_graph(n_v, seed=0, frac_long=_frac_long(n_v))
     plan = engine.plan_readability(pos, edges, radius=RADIUS,
                                    n_strips=n_strips, tier_strips=False)
     spec = gridlib.plan_graph_shards(plan.n_strips, plan.grid_nx,
@@ -194,23 +214,22 @@ def _work_model(n_v: int, n_strips: int, n_shards: int):
 
 
 def run_full():
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
-    child = _CHILD % dict(bench_dir=os.path.dirname(os.path.abspath(__file__)),
-                          radius=RADIUS)
+    import jax
+
+    on_cpu = jax.default_backend() == "cpu"
+    if not on_cpu and len(jax.devices()) < max(DEVICE_COUNTS):
+        sys.exit(f"fig4_scaling needs {max(DEVICE_COUNTS)} devices; "
+                 f"this host has {len(jax.devices())}")
     table = []
     for n_v, n_strips, reps in SIZES:
         rows = {}
         ref_ints = None
         for ndev in DEVICE_COUNTS:
-            res = subprocess.run(
-                [sys.executable, "-c", child, str(ndev), str(n_v),
-                 str(n_strips), str(_frac_long(n_v)), str(reps)],
-                env=env, capture_output=True, text=True, timeout=3600)
-            assert res.returncode == 0, res.stdout + "\n" + res.stderr
-            line = [l for l in res.stdout.splitlines()
-                    if l.startswith("RESULT ")][-1]
-            out = json.loads(line[len("RESULT "):])
+            if on_cpu:
+                out = _cpu_cell(ndev, n_v, n_strips, reps)
+            else:
+                out = measure_cell(jax.devices()[:ndev], n_v, n_strips,
+                                   _frac_long(n_v), reps)
             total, peak = _work_model(n_v, n_strips, ndev)
             out["work_speedup"] = total / peak
             rows[ndev] = out
@@ -269,17 +288,14 @@ def run_smoke() -> int:
     Runs in-process on however many devices ``--devices`` forced."""
     import jax
 
-    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-    from engine_bench import make_graph
-
     from repro.core import engine
     from repro.core import grid
-    from repro.distributed.compat import make_mesh
     from repro.distributed.graph_sharded import evaluate_graph_sharded
+    from repro.launch.elastic import serving_mesh
 
-    ndev = len(jax.devices())
-    mesh = make_mesh((ndev,), ("graph",))
-    pos, edges = make_graph(10_000, seed=0, frac_long=0.02)
+    mesh = serving_mesh("graph")
+    ndev = mesh.size
+    pos, edges = lattice_graph(10_000, seed=0, frac_long=0.02)
     plan = engine.plan_readability(pos, edges, radius=RADIUS,
                                    n_strips=256, tier_strips=False)
 
@@ -321,6 +337,7 @@ def main(argv=None):
     ap.add_argument("--devices", type=int, default=None,
                     help="force N host devices (applied pre-import)")
     args = ap.parse_args(argv)
+    use_compile_cache()
     if args.smoke:
         sys.exit(run_smoke())
     return run_full()

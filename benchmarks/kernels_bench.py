@@ -10,6 +10,7 @@ import numpy as np
 from benchmarks.common import timed
 from repro.core import count_crossings_exact, count_occlusions_exact
 from repro.kernels.ops import crossing_count_op, occlusion_count_op
+from repro.launch.compile_cache import use_compile_cache
 
 
 def run(n_vertices: int = 2048, n_edges: int = 2048):
@@ -40,6 +41,7 @@ def run(n_vertices: int = 2048, n_edges: int = 2048):
 
 
 def main(argv=None):
+    use_compile_cache()
     rows = run()
     print("name,us_per_call,derived")
     for name, sec, val in rows:

@@ -20,6 +20,8 @@ def main(argv=None):
     ap.add_argument("--full", action="store_true",
                     help="larger dataset scales (hours on 1 CPU core)")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     # default sized for the single-core container; --full for the
     # paper-scale sweep (the speedup *ratios* are scale-stable)
     scale = 0.015 if args.fast else (0.08 if args.full else 0.04)

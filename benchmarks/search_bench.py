@@ -56,28 +56,15 @@ from common import timed  # noqa: E402
 from repro.core import engine, soft  # noqa: E402
 from repro.core.keys import EvalConfig  # noqa: E402
 from repro.graphs.layouts import (fruchterman_reingold,  # noqa: E402
-                                  random_layout)
+                                  lattice_graph, random_layout)
+from repro.launch.compile_cache import use_compile_cache  # noqa: E402
 from repro.search import GradientSearch, batch_objectives  # noqa: E402
 
 
-def lattice_graph(n_v, seed=0, frac_long=0.02):
-    """engine_bench's layout-local regime: jittered lattice, neighbour
-    edges + a sprinkle of long-range ones."""
-    rng = np.random.default_rng(seed)
-    side = int(np.ceil(np.sqrt(n_v)))
-    iy, ix = np.divmod(np.arange(n_v), side)
-    pos = np.stack([ix, iy], axis=1) * (100.0 / side)
-    pos = (pos + rng.normal(0, 0.15 * 100.0 / side,
-                            size=pos.shape)).astype(np.float32)
-    right = np.stack([np.arange(n_v), np.arange(n_v) + 1], axis=1)
-    right = right[(right[:, 1] < n_v) & (ix[: right.shape[0]] + 1 < side)]
-    down = np.stack([np.arange(n_v), np.arange(n_v) + side], axis=1)
-    down = down[down[:, 1] < n_v]
-    edges = np.concatenate([right, down])
-    n_long = int(frac_long * edges.shape[0])
-    long_e = rng.integers(0, n_v, size=(2 * n_long, 2))
-    long_e = long_e[long_e[:, 0] != long_e[:, 1]][:n_long]
-    return np.concatenate([edges, long_e]).astype(np.int32)
+def lattice_edges(n_v):
+    """engine_bench's layout-local regime: lattice neighbour edges plus
+    a sprinkle of long-range ones."""
+    return lattice_graph(n_v)[1]
 
 
 def random_graph(n_v, seed=1):
@@ -109,7 +96,7 @@ def cluster_graph(n_v, seed=2, n_clusters=4):
     return np.array(sorted(edges), np.int32)
 
 
-FAMILIES = {"lattice": lattice_graph, "random": random_graph,
+FAMILIES = {"lattice": lattice_edges, "random": random_graph,
             "cluster": cluster_graph}
 
 # Per-step cost regression budget vs one evaluate_batch on the same
@@ -184,6 +171,7 @@ def main(argv=None):
                     help="tiny sizes, structural gates only, no BENCH "
                          "file; nonzero exit on regression (CI gate)")
     args = ap.parse_args(argv)
+    use_compile_cache()
     config = EvalConfig(**{"n_strips": 64, "radius": 1.0,
                            **json.loads(args.config)})
 

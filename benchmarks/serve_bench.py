@@ -63,6 +63,7 @@ sys.path.insert(0, os.path.dirname(__file__))
 from engine_bench import make_graph  # noqa: E402
 
 from repro.core.keys import EvalConfig  # noqa: E402
+from repro.launch.compile_cache import use_compile_cache  # noqa: E402
 from repro.launch.serve import ReadabilityServer  # noqa: E402
 
 SIZES = (200, 1000, 5000, 10000)
@@ -343,6 +344,7 @@ def main(argv=None):
                          "session.update latency vs warm full re-eval "
                          "at |V|=10k) and merge it into BENCH_serve.json")
     args = ap.parse_args(argv)
+    use_compile_cache()
     overrides = json.loads(args.config)
     if "metrics" in overrides:
         overrides["metrics"] = tuple(overrides["metrics"])

@@ -24,6 +24,7 @@ from repro.core import (count_crossings_enhanced, count_crossings_exact,
 from repro.graphs.datasets import PAPER_DATASETS, paper_graph
 from repro.graphs.layouts import random_layout
 from repro.kernels import ref
+from repro.launch.compile_cache import use_compile_cache
 
 
 def run(scale: float = 0.08, radius: float = 0.5, n_strips: int = 256):
@@ -92,6 +93,7 @@ def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", type=float, default=0.08)
     args = ap.parse_args(argv)
+    use_compile_cache()
     rows = run(scale=args.scale)
     print("dataset,n_v,n_e,metric,algo,us_per_call,value,speedup_vs_ref")
     for r in rows:

@@ -14,6 +14,7 @@ from repro.core import (count_crossings_enhanced, count_crossings_exact,
                         crossing_angle_enhanced, crossing_angle_exact)
 from repro.graphs.datasets import PAPER_DATASETS, paper_graph
 from repro.graphs.layouts import random_layout
+from repro.launch.compile_cache import use_compile_cache
 
 
 def run(scale: float = 0.08, n_strips: int = 512, radius: float = 0.5):
@@ -47,6 +48,7 @@ def main(argv=None):
     ap.add_argument("--scale", type=float, default=0.08)
     ap.add_argument("--n-strips", type=int, default=512)
     args = ap.parse_args(argv)
+    use_compile_cache()
     rows = run(scale=args.scale, n_strips=args.n_strips)
     print("dataset,n_v,n_e,Nc_err_pct,Ec_err_pct,Eca_err_pct")
     for r in rows:

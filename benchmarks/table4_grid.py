@@ -13,6 +13,7 @@ import numpy as np
 from repro.core import count_crossings_enhanced, count_crossings_exact
 from repro.graphs.datasets import paper_graph
 from repro.graphs.layouts import fruchterman_reingold, random_layout
+from repro.launch.compile_cache import use_compile_cache
 
 
 def run(scale: float = 0.04, n_layouts: int = 4,
@@ -43,6 +44,7 @@ def main(argv=None):
     ap.add_argument("--scale", type=float, default=0.04)
     ap.add_argument("--layouts", type=int, default=4)
     args = ap.parse_args(argv)
+    use_compile_cache()
     rows = run(scale=args.scale, n_layouts=args.layouts)
     print("n_strips,orientation,mean_err_pct,std")
     for r in rows:

@@ -16,9 +16,9 @@ import time  # noqa: E402
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
+from jax.sharding import AxisType  # noqa: E402
 
 from repro.core import grid as gridlib  # noqa: E402
-from repro.distributed.compat import AxisType, make_mesh  # noqa: E402
 from repro.core import count_crossings_exact  # noqa: E402
 from repro.distributed.gridded import sharded_reversal_stats  # noqa: E402
 from repro.distributed.pairwise import (ring_occlusion_count,  # noqa: E402
@@ -28,8 +28,8 @@ from repro.graphs.datasets import random_edges  # noqa: E402
 from repro.graphs.layouts import random_layout  # noqa: E402
 from repro.kernels import ref  # noqa: E402
 
-mesh = make_mesh((2, 4), ("data", "model"),
-                 axis_types=(AxisType.Auto, AxisType.Auto))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(AxisType.Auto, AxisType.Auto))
 print(f"mesh: {mesh}")
 
 n_v, n_e = 1500, 3000

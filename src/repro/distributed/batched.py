@@ -16,8 +16,8 @@ This composes two subsystems that were built independently:
   orientation covers the ``(B * n_strips_t, cap_t)`` rows through
   :func:`~repro.core.engine.fused_reversal_block`;
 * the mesh drivers (:mod:`repro.distributed.gridded` /
-  :mod:`repro.distributed.pairwise`): ``shard_map`` over a device mesh
-  via :mod:`repro.distributed.compat`.
+  :mod:`repro.distributed.pairwise`): ``jax.shard_map`` over a device
+  mesh.
 
 The composition is embarrassingly parallel: every per-layout value in
 the batched program is computed by per-layout-independent code (each
@@ -39,12 +39,12 @@ serving batches through it when constructed with a ``mesh``.
 from __future__ import annotations
 
 import jax
+from jax import shard_map
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import engine
 from repro.core.validate import BackendUnavailableError
-from repro.distributed.compat import shard_map
 
 
 def pad_batch_to_devices(batch_pos, n_dev: int):

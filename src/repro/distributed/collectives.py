@@ -24,10 +24,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from repro.distributed.compat import shard_map
 
 
 def merge_decode_attention(mesh: Mesh, q, k_cache, v_cache, pos, *,

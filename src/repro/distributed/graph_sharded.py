@@ -37,13 +37,13 @@ from __future__ import annotations
 import dataclasses
 
 import jax
+from jax import shard_map
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import engine
 from repro.core import grid as gridlib
 from repro.core.validate import BackendUnavailableError
-from repro.distributed.compat import shard_map
 
 
 def plan_with_shard_spec(plan, n_shards: int):

@@ -17,6 +17,7 @@ strong scaling. Mapping:
 from __future__ import annotations
 
 import jax
+from jax import shard_map
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
@@ -24,7 +25,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.core.engine import fused_reversal_block
 from repro.core.grid import SegmentBuckets
 from repro.core.validate import BackendUnavailableError, ReadabilityError
-from repro.distributed.compat import shard_map
 
 
 def _pad_strips(buckets: SegmentBuckets, n_dev: int):

@@ -23,6 +23,7 @@ from __future__ import annotations
 import functools
 
 import jax
+from jax import shard_map
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
@@ -30,7 +31,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.core.geometry import (pair_dist_sq, segments_cross,
                                  segments_cross_bool)
 from repro.core.validate import BackendUnavailableError, ReadabilityError
-from repro.distributed.compat import shard_map
 
 
 def _flat_axes(mesh: Mesh):

@@ -4,8 +4,8 @@
 The paper's 2-D dynamic segment tree exists to avoid materializing the
 crossing pairs on a sequential machine. The TPU tile *is* the
 materialized pair block, so E_ca collapses to one fused masked reduction
-over the same CCW tile the crossing count uses — two outputs per grid
-step: partial count (int32) and partial deviation sum (f32).
+over the same CCW tile the crossing count uses — two partial rows per
+row tile: count (int32) and deviation sum (f32).
 """
 
 from __future__ import annotations
@@ -14,11 +14,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 from jax.experimental import pallas as pl
 
 from repro.core.grid import count_dtype
-from repro.kernels.segment_crossing import _cross_tile
+from repro.kernels.segment_crossing import pair_grid_call, pair_tile_mask
 
 TILE_I = 256
 TILE_J = 256
@@ -28,46 +27,32 @@ def _angle_kernel(x1i, y1i, x2i, y2i, thi, vi, ui, oki,
                   x1j, y1j, x2j, y2j, thj, vj, uj, okj,
                   count_ref, dev_ref, *, ideal: float, tile_i: int,
                   tile_j: int):
-    gi = pl.program_id(0)
-    gj = pl.program_id(1)
-    a = lambda r: r[...][:, None]
-    b = lambda r: r[...][None, :]
-    cross = _cross_tile(a(x1i), a(y1i), a(x2i), a(y2i),
-                        b(x1j), b(y1j), b(x2j), b(y2j))
-    shared = ((a(vi) == b(vj)) | (a(vi) == b(uj)) |
-              (a(ui) == b(vj)) | (a(ui) == b(uj)))
-    rows = gi * tile_i + lax.broadcasted_iota(jnp.int32, (tile_i, tile_j), 0)
-    cols = gj * tile_j + lax.broadcasted_iota(jnp.int32, (tile_i, tile_j), 1)
-    mask = (rows < cols) & (a(oki) > 0) & (b(okj) > 0) & ~shared & cross
-    d = jnp.abs(a(thi) - b(thj))
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        count_ref[...] = jnp.zeros_like(count_ref)
+        dev_ref[...] = jnp.zeros_like(dev_ref)
+
+    mask = pair_tile_mask(x1i, y1i, x2i, y2i, vi, ui, oki,
+                          x1j, y1j, x2j, y2j, vj, uj, okj,
+                          tile_i=tile_i, tile_j=tile_j)
+    d = jnp.abs(thi[...].reshape(tile_i, 1) - thj[...])
     a_c = jnp.minimum(d, jnp.pi - d)
     dev = jnp.abs(ideal - a_c) * (1.0 / ideal)
-    count_ref[0, 0] = jnp.sum(mask.astype(jnp.int32))
-    dev_ref[0, 0] = jnp.sum(jnp.where(mask, dev, 0.0))
+    count_ref[...] += jnp.sum(mask.astype(jnp.int32), axis=0, keepdims=True)
+    dev_ref[...] += jnp.sum(jnp.where(mask, dev, 0.0), axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("ideal", "tile_i", "tile_j",
                                              "interpret"))
 def crossing_angle_stats(x1, y1, x2, y2, theta, v, u, valid, *, ideal: float,
-                         tile_i: int = TILE_I, tile_j: int = TILE_J,
-                         interpret: bool = True):
+                         interpret: bool, tile_i: int = TILE_I,
+                         tile_j: int = TILE_J):
     """Returns (crossing count, sum of |ideal - a_c| / ideal)."""
-    n = x1.shape[0]
-    assert n % tile_i == 0 and n % tile_j == 0, (n, tile_i, tile_j)
-    grid = (n // tile_i, n // tile_j)
     kernel = functools.partial(_angle_kernel, ideal=float(ideal),
                                tile_i=tile_i, tile_j=tile_j)
-    row_spec = pl.BlockSpec((tile_i,), lambda i, j: (i,))
-    col_spec = pl.BlockSpec((tile_j,), lambda i, j: (j,))
-    out_spec = pl.BlockSpec((1, 1), lambda i, j: (i, j))
-    counts, devs = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[row_spec] * 8 + [col_spec] * 8,
-        out_specs=(out_spec, out_spec),
-        out_shape=(jax.ShapeDtypeStruct(grid, jnp.int32),
-                   jax.ShapeDtypeStruct(grid, jnp.float32)),
-        interpret=interpret,
-    )(x1, y1, x2, y2, theta, v, u, valid,
-      x1, y1, x2, y2, theta, v, u, valid)
+    # the kernel's ref order is (x1, y1, x2, y2, theta, v, u, valid)
+    counts, devs = pair_grid_call(
+        kernel, (x1, y1, x2, y2, theta, v, u, valid),
+        (jnp.int32, jnp.float32), tile_i=tile_i, tile_j=tile_j,
+        interpret=interpret)
     return jnp.sum(counts, dtype=count_dtype()), jnp.sum(devs)

@@ -1,9 +1,13 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 Handle padding to tile multiples, layout (SoA coordinate vectors), the
-interpret-mode switch (CPU validation vs TPU execution), and the
-layout->kernel-argument plumbing so callers pass ``(pos, edges)`` like the
-pure-jnp API.
+interpret-mode switch, and the layout->kernel-argument plumbing so callers
+pass ``(pos, edges)`` like the pure-jnp API.
+
+:func:`_interpret` is the ONE place that chooses interpret mode: the
+Pallas interpreter on the CPU backend (validation), the compiled Mosaic
+kernels on TPU, and an error anywhere else — a kernel route never runs
+the interpreter silently on an accelerator.
 """
 
 from __future__ import annotations
@@ -13,16 +17,22 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.geometry import edge_endpoints, segment_theta
+from repro.core.validate import BackendUnavailableError
 from repro.kernels.crossing_angle_sum import crossing_angle_stats
 from repro.kernels.occlusion_pairs import occlusion_count
 from repro.kernels.segment_crossing import crossing_count
 from repro.kernels.strip_reversal import strip_reversal_stats
 
 
-def _auto_interpret(interpret):
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return interpret
+def _interpret() -> bool:
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise BackendUnavailableError(
+        f"the Pallas kernels run compiled on TPU or interpreted on CPU; "
+        f"the default backend is {backend!r}")
 
 
 def _pad1(a, n, fill):
@@ -32,8 +42,7 @@ def _pad1(a, n, fill):
     return jnp.concatenate([a, jnp.full((pad,), fill, a.dtype)])
 
 
-def occlusion_count_op(pos, radius, *, valid=None, tile: int = 512,
-                       interpret=None):
+def occlusion_count_op(pos, radius, *, valid=None, tile: int = 512):
     """N_c via the Pallas pairwise kernel."""
     pos = jnp.asarray(pos, jnp.float32)
     n = pos.shape[0]
@@ -46,7 +55,7 @@ def occlusion_count_op(pos, radius, *, valid=None, tile: int = 512,
     y = _pad1(pos[:, 1], n_pad, 0.0)
     ok = _pad1(valid, n_pad, 0)
     return occlusion_count(x, y, ok, radius=float(radius), tile_i=tile,
-                           tile_j=tile, interpret=_auto_interpret(interpret))
+                           tile_j=tile, interpret=_interpret())
 
 
 def _edge_arrays(pos, edges, valid, tile):
@@ -67,25 +76,22 @@ def _edge_arrays(pos, edges, valid, tile):
             _pad1(valid, e_pad, 0))
 
 
-def crossing_count_op(pos, edges, *, valid=None, tile: int = 256,
-                      interpret=None):
+def crossing_count_op(pos, edges, *, valid=None, tile: int = 256):
     """E_c via the Pallas CCW kernel."""
     x1, y1, x2, y2, _, v, u, ok = _edge_arrays(pos, edges, valid, tile)
     return crossing_count(x1, y1, x2, y2, v, u, ok, tile_i=tile, tile_j=tile,
-                          interpret=_auto_interpret(interpret))
+                          interpret=_interpret())
 
 
-def crossing_angle_op(pos, edges, *, ideal, valid=None, tile: int = 256,
-                      interpret=None):
+def crossing_angle_op(pos, edges, *, ideal, valid=None, tile: int = 256):
     """(count, deviation sum) via the fused Pallas kernel."""
     x1, y1, x2, y2, theta, v, u, ok = _edge_arrays(pos, edges, valid, tile)
     return crossing_angle_stats(x1, y1, x2, y2, theta, v, u, ok,
                                 ideal=float(ideal), tile_i=tile, tile_j=tile,
-                                interpret=_auto_interpret(interpret))
+                                interpret=_interpret())
 
 
-def strip_reversal_op(buckets, *, ideal: float = 1.0, with_angle=False,
-                      interpret=None):
+def strip_reversal_op(buckets, *, ideal: float = 1.0, with_angle=False):
     """Enhanced-crossing inner loop via the bucketed Pallas kernel.
 
     ``buckets`` is a :class:`repro.core.grid.SegmentBuckets`.
@@ -107,4 +113,4 @@ def strip_reversal_op(buckets, *, ideal: float = 1.0, with_angle=False,
         pad(buckets.u.astype(jnp.int32), -2),
         pad(buckets.valid.astype(jnp.int32), 0),
         ideal=float(ideal), with_angle=with_angle,
-        interpret=_auto_interpret(interpret))
+        interpret=_interpret())

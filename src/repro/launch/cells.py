@@ -568,8 +568,7 @@ def make_cell(arch_id: str, shape_id: str, mesh: Mesh, *,
 
 def lower_cell(cell: Cell, mesh: Mesh):
     """AOT-lower a cell on its mesh (no allocation)."""
-    from repro.distributed.compat import set_mesh
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(cell.fn, in_shardings=cell.in_shardings,
                          out_shardings=cell.out_shardings) \
             if cell.in_shardings is not None else cell.fn

@@ -34,9 +34,8 @@ def run_cell(arch_id, shape_id, mesh, mesh_name):
     t0 = time.time()
     compiled = lowered.compile()
     t_compile = time.time() - t0
-    from repro.roofline.analysis import cost_analysis_dict
     mem = compiled.memory_analysis()
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     rec = {
         "arch": arch_id, "shape": shape_id, "mesh": mesh_name,
         "status": "ok", "lower_s": round(t_lower, 2),

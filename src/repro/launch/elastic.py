@@ -30,8 +30,7 @@ collectives only.
 from __future__ import annotations
 
 import jax
-
-from repro.distributed.compat import AxisType, make_mesh
+from jax.sharding import AxisType
 
 
 def choose_mesh_shape(n_devices: int, *, max_model: int = 16, axes: int = 2):
@@ -73,14 +72,15 @@ def serving_mesh(axis: str = "eval", *, shards=None, devices=None):
     if shards is not None:
         n = min(n, int(shards))
     (n,) = choose_mesh_shape(n, axes=1)
-    return make_mesh((n,), (axis,), devices=list(devices)[:n])
+    return jax.make_mesh((n,), (axis,), axis_types=(AxisType.Auto,),
+                         devices=list(devices)[:n])
 
 
 def make_elastic_mesh():
     n = len(jax.devices())
     shape = choose_mesh_shape(n)
-    return make_mesh(shape, ("data", "model"),
-                     axis_types=(AxisType.Auto, AxisType.Auto))
+    return jax.make_mesh(shape, ("data", "model"),
+                         axis_types=(AxisType.Auto, AxisType.Auto))
 
 
 def elastic_restore(directory: str, template, sharding_fn):

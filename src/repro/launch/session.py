@@ -121,6 +121,7 @@ deprecation shim mapping onto :class:`~repro.core.keys.EvalConfig`.
 from __future__ import annotations
 
 import dataclasses
+import logging
 import threading
 from collections import Counter, OrderedDict
 
@@ -138,6 +139,8 @@ from repro.core.validate import (BackendUnavailableError, CancelledError,
                                  ReadabilityError, validate_request)
 from repro.launch import admission, faults
 from repro.launch.admission import CircuitBreaker
+
+_log = logging.getLogger(__name__)
 
 # Park coordinate for padded tail vertices: far outside any real layout
 # extent.  Correctness rests on the n_valid masks, not on this value —
@@ -337,6 +340,11 @@ class EvalSession:
         # or breaker events into shared session state
         self._publish_lock = threading.Lock()
         self._last_abandoned_worker = None
+        # the first mesh-rung failure is logged with its chained cause
+        # (later ones only count): a mesh that never works is visible
+        # without flooding the log
+        self._degrade_logged = False
+        self._degrade_log_lock = threading.Lock()
         # traces counts engine traces triggered by this session (warmup
         # compiles land here; a steady-state delta of zero is the
         # "no retrace" certificate the serve benchmark asserts on)
@@ -487,9 +495,10 @@ class EvalSession:
                            for r in results]
                 stats["traces"] += engine.trace_count() - t0
                 return faults.storm_overflow(reports)
-            except Exception:
+            except Exception as err:
                 breaker.record_failure()
                 stats["degraded_dispatches"] += 1
+                self._log_degraded("graph_sharded", err)
         if len(chunk) == 1:
             res = engine.evaluate_planned(
                 plan, chunk[0]["pos_p"], chunk[0]["edges_p"], n_v, n_e,
@@ -515,12 +524,13 @@ class EvalSession:
                         n_valid_vertices=n_v, n_valid_edges=n_e)
                     breaker.record_success()
                     stats["sharded_dispatches"] += 1
-                except Exception:
+                except Exception as err:
                     # one rung down the ladder: fused single-host (same
                     # batched body, bit-identical integer metrics); the
                     # breaker opens and re-probes on its own schedule
                     breaker.record_failure()
                     stats["degraded_dispatches"] += 1
+                    self._log_degraded("sharded", err)
                     res = None
             if res is None:
                 res = engine.evaluate_layouts(
@@ -534,6 +544,15 @@ class EvalSession:
             breaker.record_fallback_success()
         stats["traces"] += engine.trace_count() - t0
         return faults.storm_overflow(reports)
+
+    def _log_degraded(self, rung: str, err: BaseException) -> None:
+        with self._degrade_log_lock:
+            if self._degrade_logged:
+                return
+            self._degrade_logged = True
+        _log.warning("%s dispatch over %d devices failed; serving from the "
+                     "fused single-host rung (logged once per session)",
+                     rung, self.mesh.size, exc_info=err)
 
     # -- the hung-dispatch watchdog ------------------------------------------
 

@@ -130,25 +130,12 @@ class RooflineTerms:
                 f"| {self.note} |")
 
 
-def cost_analysis_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` normalized to ONE dict.
-
-    jax <= 0.4.x returns a singleton *list* of per-computation dicts
-    (and ``None`` when XLA reports nothing); modern jax returns the dict
-    directly.  Every cost lookup goes through here so the extractor works
-    on both sides of the change."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
-
-
 def _measure(cell, mesh):
     """Lower+compile one cell; return (flops, bytes, coll_bytes) per-dev."""
     from repro.launch.cells import lower_cell
     lowered = lower_cell(cell, mesh)
     compiled = lowered.compile()
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     coll = collective_bytes(compiled.as_text())
     return (float(cost.get("flops", 0.0) or 0.0),
             float(cost.get("bytes accessed", 0.0) or 0.0),

@@ -260,7 +260,7 @@ class GradientSearch:
                 return losses, grads
         else:
             from jax.sharding import PartitionSpec as P
-            from repro.distributed.compat import shard_map
+            from jax import shard_map
             axes = tuple(mesh.axis_names)
 
             def shard_fn(pos_shard, edges_rep, tau, *valid):

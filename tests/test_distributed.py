@@ -16,12 +16,12 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax
 import jax.numpy as jnp
 import numpy as np
-from repro.distributed.compat import AxisType, make_mesh
+from jax.sharding import AxisType
 
 assert len(jax.devices()) == 8
 
-mesh = make_mesh((2, 4), ("data", "model"),
-                 axis_types=(AxisType.Auto, AxisType.Auto))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(AxisType.Auto, AxisType.Auto))
 
 rng = np.random.default_rng(0)
 n_v, n_e = 300, 600

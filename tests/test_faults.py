@@ -432,9 +432,9 @@ import os, sys, json
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax
 import numpy as np
+from jax.sharding import AxisType
 
 from repro.core.keys import EvalConfig
-from repro.distributed.compat import make_mesh
 from repro.launch.faults import FaultPlan
 from repro.launch.session import EvalSession
 
@@ -452,7 +452,7 @@ reqs = [(pos + rng.normal(0, 1.5, pos.shape).astype(np.float32), edges)
         for _ in range(4)]
 
 config = EvalConfig(radius=2.0, n_strips=48)
-mesh = make_mesh((4,), ("eval",))
+mesh = jax.make_mesh((4,), ("eval",), axis_types=(AxisType.Auto,))
 
 # ground truth: a single-host session (no mesh at all)
 truth = EvalSession(config).evaluate_batch(reqs)
@@ -535,9 +535,9 @@ import os, sys, json
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax
 import numpy as np
+from jax.sharding import AxisType
 
 from repro.core.keys import EvalConfig
-from repro.distributed.compat import make_mesh
 from repro.launch.faults import FaultPlan
 from repro.launch.session import EvalSession
 
@@ -555,7 +555,7 @@ reqs = [(pos + rng.normal(0, 1.5, pos.shape).astype(np.float32), edges)
         for _ in range(4)]
 
 config = EvalConfig(radius=2.0, n_strips=48)
-mesh = make_mesh((4,), ("eval",))
+mesh = jax.make_mesh((4,), ("eval",), axis_types=(AxisType.Auto,))
 
 def same(batch, truth):
     return [[s.edge_crossing, s.node_occlusion] ==

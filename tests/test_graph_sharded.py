@@ -39,11 +39,11 @@ os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
                            + sys.argv[1])
 import jax
 import numpy as np
+from jax.sharding import AxisType
 
 from repro.core import engine
 from repro.core import grid
 from repro.core.keys import EvalConfig, pow2_bucket
-from repro.distributed.compat import make_mesh
 from repro.distributed.graph_sharded import evaluate_graph_sharded
 
 ndev = int(sys.argv[1])
@@ -65,7 +65,7 @@ n_e = edges.shape[0]
 # strip-sharded distributed driver)
 plan = engine.plan_readability(pos, edges, radius=2.0, n_strips=48,
                                tier_strips=False)
-mesh = make_mesh((ndev,), ("graph",))
+mesh = jax.make_mesh((ndev,), ("graph",), axis_types=(AxisType.Auto,))
 
 
 def fetch(res):
@@ -227,8 +227,9 @@ def _fixture(n_v=120, seed=5):
 
 
 def _mesh1():
-    from repro.distributed.compat import make_mesh
-    return make_mesh((1,), ("x",))
+    import jax
+    from jax.sharding import AxisType
+    return jax.make_mesh((1,), ("x",), axis_types=(AxisType.Auto,))
 
 
 def test_graph_sharded_dispatch_failure_is_typed(monkeypatch):
@@ -305,10 +306,13 @@ def test_gridded_dispatch_failure_is_typed(monkeypatch):
     assert ei.value.request_index == 0
 
 
-def test_session_degrades_graph_sharded_to_fused(monkeypatch):
+def test_session_degrades_graph_sharded_to_fused(monkeypatch, caplog):
     """Mesh loss mid-serve: the graph_sharded rung fails, the session
     falls down the ladder to single-host fused, the request still gets
-    valid scores, and the degradation is visible in stats/health."""
+    valid scores, and the degradation is visible in stats/health and
+    logged once with its cause."""
+    import logging
+
     from repro.api import EvalConfig, Evaluator
     from repro.core.validate import BackendUnavailableError
     from repro.distributed import graph_sharded as gs
@@ -322,18 +326,26 @@ def test_session_degrades_graph_sharded_to_fused(monkeypatch):
     monkeypatch.setattr(gs, "evaluate_graph_sharded", boom)
     ev = Evaluator(EvalConfig(radius=1.0, n_strips=16,
                               backend="graph_sharded"))
-    got = ev.evaluate(pos, edges)
+    with caplog.at_level(logging.WARNING, logger="repro.launch.session"):
+        got = ev.evaluate(pos, edges)
+        sess = ev._bound_session()
+        sess.restore_mesh()                 # the rung is tried, and fails,
+        ev.evaluate(pos + 0.5, edges)       # a second time
     assert int(got.node_occlusion) == int(ref.node_occlusion)
     assert int(got.edge_crossing) == int(ref.edge_crossing)
-    sess = ev._bound_session()
-    assert sess.stats["degraded_dispatches"] >= 1
+    assert sess.stats["degraded_dispatches"] >= 2
     assert sess.stats["graph_sharded_dispatches"] == 0
     assert sess.health()["dispatch_mode"] != "graph_sharded"
+    logged = [r for r in caplog.records if r.name == "repro.launch.session"]
+    assert len(logged) == 1
+    assert "mesh lost" in str(logged[0].exc_info[1])
 
 
 def test_graph_sharded_rejects_bad_shapes():
     from repro.core import engine
-    from repro.distributed.compat import make_mesh
+    import jax
+    from jax.sharding import AxisType
+
     from repro.distributed.graph_sharded import evaluate_graph_sharded
 
     pos, edges = _fixture()
@@ -342,6 +354,7 @@ def test_graph_sharded_rejects_bad_shapes():
     with pytest.raises(ValueError):
         evaluate_graph_sharded(_mesh1(), plan,
                                np.stack([pos, pos]), edges)
-    mesh2d = make_mesh((1, 1), ("a", "b"))
+    mesh2d = jax.make_mesh((1, 1), ("a", "b"),
+                           axis_types=(AxisType.Auto,) * 2)
     with pytest.raises(ValueError):
         evaluate_graph_sharded(mesh2d, plan, pos, edges)

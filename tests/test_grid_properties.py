@@ -13,15 +13,15 @@ Two invariants carry the whole batched/sharded engine:
   headroom), using <= 3 descending pow2-boundary tiers that partition
   the strips.
 
-Hypothesis drives the arbitrary-input versions (skipped without it, per
-``tests/_hypothesis_compat.py``); seeded deterministic twins keep the
-same invariants exercised on containers without hypothesis.
+Hypothesis drives the arbitrary-input versions; seeded deterministic
+twins pin the same invariants on fixed inputs.
 """
 
-import numpy as np
 import jax.numpy as jnp
+import numpy as np
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
-from _hypothesis_compat import given, settings, st  # noqa: E402
 from repro.core import grid as gridlib
 
 

@@ -4,10 +4,8 @@ swept over shapes and dtypes, plus hypothesis property tests."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-# property tests skip when hypothesis is absent; the deterministic
-# shape sweeps below still run
-from _hypothesis_compat import given, settings, st  # noqa: E402
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import grid as gridlib
 from repro.core.crossing_angle import DEFAULT_IDEAL

@@ -24,7 +24,8 @@ import time
 
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core.keys import EvalConfig
 from repro.core.validate import (CancelledError, DeadlineExceededError,

@@ -7,10 +7,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-# property tests skip when hypothesis is absent; the deterministic
-# equivalence tests below still run
-from _hypothesis_compat import given, settings, st  # noqa: E402
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core.geometry import segments_cross, segments_cross_bool
 

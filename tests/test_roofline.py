@@ -5,8 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.roofline.analysis import (collective_bytes, cost_analysis_dict,
-                                     _shape_bytes)
+from repro.roofline.analysis import collective_bytes, _shape_bytes
 
 
 def test_shape_bytes():
@@ -44,8 +43,8 @@ def test_cost_analysis_loop_semantics():
     def inline(x, y):
         return ((x @ y) @ y.T) @ y                    # 3 dots
 
-    flops_inline = cost_analysis_dict(
-        jax.jit(inline).lower(a, b).compile())["flops"]
+    flops_inline = jax.jit(inline).lower(a, b).compile().cost_analysis()[
+        "flops"]
     assert abs(flops_inline - 3 * 2 * m * k * n) / flops_inline < 0.05
 
     def with_scan(x, y, length):
@@ -54,27 +53,26 @@ def test_cost_analysis_loop_semantics():
         out, _ = jax.lax.scan(body, x, None, length=length)
         return out
 
-    f1 = cost_analysis_dict(jax.jit(lambda x, y: with_scan(x, y, 1)).lower(
-        a, b).compile())["flops"]
-    f8 = cost_analysis_dict(jax.jit(lambda x, y: with_scan(x, y, 8)).lower(
-        a, b).compile())["flops"]
+    f1 = jax.jit(lambda x, y: with_scan(x, y, 1)).lower(
+        a, b).compile().cost_analysis()["flops"]
+    f8 = jax.jit(lambda x, y: with_scan(x, y, 8)).lower(
+        a, b).compile().cost_analysis()["flops"]
     # body counted once regardless of trip count
     assert f1 >= 2 * m * k * n
     assert abs(f8 - f1) / f1 < 0.05
 
 
 def test_analyze_cell_small_mesh():
-    # AxisType / make_mesh go through the distributed compat shims: on
-    # jax 0.4.x jax.sharding has no AxisType and make_mesh no axis_types
-    from repro.distributed.compat import AxisType, make_mesh
+    from jax.sharding import AxisType
+
     from repro.roofline.analysis import analyze_cell
 
     if len(jax.devices()) < 2:
-        mesh = make_mesh((1, 1), ("data", "model"),
-                         axis_types=(AxisType.Auto,) * 2)
+        mesh = jax.make_mesh((1, 1), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
     else:
-        mesh = make_mesh((1, 2), ("data", "model"),
-                         axis_types=(AxisType.Auto,) * 2)
+        mesh = jax.make_mesh((1, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
     terms = analyze_cell("xdeepfm", "serve_p99", mesh, "test")
     assert terms.compute_s > 0
     assert terms.memory_s > 0

@@ -27,7 +27,7 @@ import numpy as np
 from repro.core import engine
 from repro.core.keys import pow2_bucket
 from repro.distributed.batched import evaluate_layouts_sharded
-from repro.distributed.compat import make_mesh
+from jax.sharding import AxisType
 
 ndev = int(sys.argv[1])
 assert len(jax.devices()) == ndev
@@ -46,7 +46,7 @@ batch = np.stack([pos + rng.normal(0, 1.0, pos.shape).astype(np.float32)
                   for _ in range(B)])
 
 plan = engine.plan_readability(batch, edges, radius=2.0, n_strips=48)
-mesh = make_mesh((ndev,), ("batch",))
+mesh = jax.make_mesh((ndev,), ("batch",), axis_types=(AxisType.Auto,))
 
 def fetch(res):
     res = jax.device_get(res)

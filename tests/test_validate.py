@@ -8,7 +8,8 @@ bit-identical)."""
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 from repro.core import engine
 from repro.core.validate import (VALIDATION_MODES, BackendUnavailableError,
                                  CapacityError, InvalidInputError,
