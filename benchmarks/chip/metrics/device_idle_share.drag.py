@@ -1,0 +1,8 @@
+"""Device idle share of the traced window in the drag cells: 1 minus the
+union of device-operation intervals over the window, in percent."""
+
+from trace_reduce import idle_share
+
+
+def read(rec):
+    return idle_share(rec, "drag")
