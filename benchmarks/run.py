@@ -38,8 +38,8 @@ def main(argv=None):
             traceback.print_exc()
             sections.append((name, "FAIL", time.time() - t0))
 
-    from benchmarks import (fig4_scaling, kernels_bench, table2_runtime,
-                            table3_accuracy, table4_grid)
+    from benchmarks import (fig4_scaling, table2_runtime, table3_accuracy,
+                            table4_grid)
 
     section("table2_runtime (paper Table 2 / Figs 2-3)",
             lambda: table2_runtime.main(["--scale", str(scale)]))
@@ -51,7 +51,6 @@ def main(argv=None):
                                       "--layouts", "3"]))
     section("fig4_scaling (paper Fig 4)",
             lambda: fig4_scaling.main(["--scale", str(scale)]))
-    section("kernels (Pallas interpret-mode)", kernels_bench.main)
 
     print("\n===== summary =====")
     print("section,status,seconds")

@@ -43,6 +43,7 @@ this module.
 
 from __future__ import annotations
 
+from repro import tracing
 from repro.core import engine
 from repro.core.engine import ALL_METRICS  # noqa: F401  (re-export)
 from repro.core.keys import (EvalConfig, pow2_bucket,  # noqa: F401
@@ -289,14 +290,20 @@ class Evaluator:
         typed :class:`InvalidInputError` on out-of-range edges or a
         non-finite member layout; sanitize repairs the topology once for
         the whole batch and records it in ``scores.flags``."""
+        with tracing.span("evaluator.evaluate_batch"):
+            return self._evaluate_batch(batch_pos, edges, plan)
+
+    def _evaluate_batch(self, batch_pos, edges, plan):
+        import jax
         import numpy as np
-        batch_pos = np.asarray(batch_pos, np.float32)
-        edges = np.asarray(edges, np.int32)
-        if batch_pos.ndim != 3:
-            raise ValueError("evaluate_batch wants a (B, V, 2) batch; "
-                             f"got shape {batch_pos.shape}")
-        batch_pos, edges, flags = validate_batch(
-            batch_pos, edges, mode=self.config.validation)
+        with tracing.span("evaluator.validate"):
+            batch_pos = np.asarray(batch_pos, np.float32)
+            edges = np.asarray(edges, np.int32)
+            if batch_pos.ndim != 3:
+                raise ValueError("evaluate_batch wants a (B, V, 2) batch; "
+                                 f"got shape {batch_pos.shape}")
+            batch_pos, edges, flags = validate_batch(
+                batch_pos, edges, mode=self.config.validation)
         n_v, n_e = batch_pos.shape[1], edges.shape[0]
         backend = self.config.backend
         if n_v == 0 or n_e == 0:
@@ -311,15 +318,16 @@ class Evaluator:
             edges_p[:n_e] = edges
             if plan is None:
                 plan = self.plan(batch_pos, edges)
-            if backend == "eager":
-                res = engine._evaluate_batched(
-                    plan, pos_p, edges_p, np.int32(n_v), np.int32(n_e))
-            else:
-                res = engine.evaluate_layouts(
-                    plan, pos_p, edges_p, np.int32(n_v), np.int32(n_e),
-                    use_kernels=self.config.use_kernels)
-            import jax
-            res = jax.device_get(res)
+            with tracing.span("engine.dispatch"):
+                if backend == "eager":
+                    res = engine._evaluate_batched(
+                        plan, pos_p, edges_p, np.int32(n_v), np.int32(n_e))
+                else:
+                    res = engine.evaluate_layouts(
+                        plan, pos_p, edges_p, np.int32(n_v), np.int32(n_e),
+                        use_kernels=self.config.use_kernels)
+            with tracing.span("scores.fetch"):
+                res = jax.device_get(res)
             return res._replace(n_vertices=n_v, n_edges=n_e, flags=flags)
         if backend == "distributed":
             # mesh-sharded native batching: the batch axis shards over
@@ -330,9 +338,10 @@ class Evaluator:
             mesh = self._mesh()
             if plan is None:
                 plan = self.plan(batch_pos, edges)
-            import jax
-            res = jax.device_get(
-                evaluate_layouts_sharded(mesh, plan, batch_pos, edges))
+            with tracing.span("engine.dispatch"):
+                res = evaluate_layouts_sharded(mesh, plan, batch_pos, edges)
+            with tracing.span("scores.fetch"):
+                res = jax.device_get(res)
             return res._replace(n_vertices=n_v, n_edges=n_e, flags=flags)
         if backend == "graph_sharded":
             # spatial partitioning is per-layout: each member IS the
@@ -341,31 +350,33 @@ class Evaluator:
             # mesh are static and shared).  Flat strips: the per-device
             # slot maps must be SPMD-uniform, so tiers are off.
             from repro.distributed.graph_sharded import evaluate_graph_sharded
-            import jax
             mesh = self._mesh()
             if plan is None:
                 plan = engine.plan_readability(
                     batch_pos, edges,
                     **self.config.plan_kwargs(tier_default=False))
-            results = [jax.device_get(
-                           evaluate_graph_sharded(mesh, plan,
-                                                  batch_pos[i], edges))
-                       for i in range(batch_pos.shape[0])]
+            results = []
+            for i in range(batch_pos.shape[0]):
+                with tracing.span("engine.dispatch"):
+                    res = evaluate_graph_sharded(mesh, plan, batch_pos[i],
+                                                 edges)
+                with tracing.span("scores.fetch"):
+                    results.append(jax.device_get(res))
             res = jax.tree_util.tree_map(
                 lambda *xs: np.stack(xs), *results)
             return res._replace(n_vertices=n_v, n_edges=n_e, flags=flags)
         if plan is None:
             plan = self.plan(batch_pos, edges)
-        if backend == "eager":
-            res = engine._evaluate_batched(plan, batch_pos, edges)
-        else:
-            res = engine.evaluate_layouts(
-                plan, batch_pos, edges,
-                use_kernels=self.config.use_kernels)
-        import jax
-        res = jax.device_get(res)
+        with tracing.span("engine.dispatch"):
+            if backend == "eager":
+                res = engine._evaluate_batched(plan, batch_pos, edges)
+            else:
+                res = engine.evaluate_layouts(
+                    plan, batch_pos, edges,
+                    use_kernels=self.config.use_kernels)
+        with tracing.span("scores.fetch"):
+            res = jax.device_get(res)
         return res._replace(n_vertices=n_v, n_edges=n_e, flags=flags)
-
 
     # -- search -------------------------------------------------------------
 

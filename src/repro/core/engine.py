@@ -378,22 +378,25 @@ def _tiered_strip_stats(plan: "ReadabilityPlan", axis_i: int, segs, B: int,
     """
     n_strips = plan.n_strips
     strip_off, strip_cap, total, slabs = _tier_layout(plan, axis_i)
-    yl, yr, th, v, u, ok, _, dropped = gridlib.gather_ragged_buckets(
-        segs.strip, n_strips, strip_off, strip_cap,
-        segs.yl, segs.yr, segs.theta, segs.v, segs.u, valid=segs.valid)
+    with jax.named_scope(f"strips.build/axis{axis_i}"):
+        yl, yr, th, v, u, ok, _, dropped = gridlib.gather_ragged_buckets(
+            segs.strip, n_strips, strip_off, strip_cap,
+            segs.yl, segs.yr, segs.theta, segs.v, segs.u, valid=segs.valid)
 
     gridlib.CALL_COUNTS["reversal_sweeps"] += 1
     cnt = jnp.zeros(B, gridlib.count_dtype())
     dev = jnp.zeros(B, yl.dtype)
     row_block = min(plan.strip_block, n_strips)
-    for off, n_t, cap_t in slabs:
+    for tier, (off, n_t, cap_t) in enumerate(slabs):
         sl = lambda a: (a[:, off:off + n_t * cap_t]
                         .reshape(B * n_t, cap_t))
-        rc, rd = _reversal_rows(sl(yl), sl(yr), sl(th), sl(v), sl(u),
-                                sl(ok), ideal=plan.ideal,
-                                with_angle=with_angle, row_block=row_block)
-        cnt = cnt + rc.reshape(B, n_t).sum(axis=1)
-        dev = dev + rd.reshape(B, n_t).sum(axis=1)
+        with jax.named_scope(f"strips.sweep/axis{axis_i}/tier{tier}"):
+            rc, rd = _reversal_rows(sl(yl), sl(yr), sl(th), sl(v), sl(u),
+                                    sl(ok), ideal=plan.ideal,
+                                    with_angle=with_angle,
+                                    row_block=row_block)
+            cnt = cnt + rc.reshape(B, n_t).sum(axis=1)
+            dev = dev + rd.reshape(B, n_t).sum(axis=1)
     return cnt, dev, dropped
 
 
@@ -482,26 +485,31 @@ def _evaluate(plan: ReadabilityPlan, pos, edges, use_kernels: bool,
     overflow = jnp.zeros((), jnp.int32)
 
     if "node_occlusion" in m:
-        if use_kernels:
-            # exact tiled pairwise Pallas kernel: same count as the grid
-            # (paper Table 3: enhanced N_c has 0% error), no capacities to
-            # overflow
-            from repro.kernels.ops import occlusion_count_op
-            cnt = occlusion_count_op(pos, plan.radius, valid=vertex_valid)
-        else:
-            cnt, ov = count_occlusions_gridded(
-                pos, plan.radius, plan.grid_origin, plan.grid_nx,
-                plan.grid_ny, plan.cell_cap, valid=vertex_valid,
-                cell_block=min(plan.cell_block, plan.grid_nx * plan.grid_ny),
-                cell_size=plan.grid_cell_size)
-            overflow = overflow + ov
+        with jax.named_scope("occlusion"):
+            if use_kernels:
+                # exact tiled pairwise Pallas kernel: same count as the
+                # grid (paper Table 3: enhanced N_c has 0% error), no
+                # capacities to overflow
+                from repro.kernels.ops import occlusion_count_op
+                cnt = occlusion_count_op(pos, plan.radius,
+                                         valid=vertex_valid)
+            else:
+                cnt, ov = count_occlusions_gridded(
+                    pos, plan.radius, plan.grid_origin, plan.grid_nx,
+                    plan.grid_ny, plan.cell_cap, valid=vertex_valid,
+                    cell_block=min(plan.cell_block,
+                                   plan.grid_nx * plan.grid_ny),
+                    cell_size=plan.grid_cell_size)
+                overflow = overflow + ov
         out["node_occlusion"] = cnt
     if "minimum_angle" in m:
-        m_a, _ = minimum_angle(pos, edges, edge_valid=edge_valid)
+        with jax.named_scope("min_angle"):
+            m_a, _ = minimum_angle(pos, edges, edge_valid=edge_valid)
         out["minimum_angle"] = m_a
     if "edge_length_variation" in m:
-        out["edge_length_variation"] = edge_length_variation(
-            pos, edges, edge_valid=edge_valid)
+        with jax.named_scope("edge_length"):
+            out["edge_length_variation"] = edge_length_variation(
+                pos, edges, edge_valid=edge_valid)
 
     want_ec = "edge_crossing" in m
     want_eca = "edge_crossing_angle" in m
@@ -511,19 +519,22 @@ def _evaluate(plan: ReadabilityPlan, pos, edges, use_kernels: bool,
                 zip(plan.axes, plan.strip_plans)):
             # strip build + bucketing happen ONCE per orientation; the one
             # fused sweep serves both E_c and E_ca
-            segs = gridlib.build_strip_segments(
-                pos, edges, plan.n_strips, max_segments, axis=axis,
-                edge_valid=edge_valid)
+            with jax.named_scope(f"strips.build/axis{axis_i}"):
+                segs = gridlib.build_strip_segments(
+                    pos, edges, plan.n_strips, max_segments, axis=axis,
+                    edge_valid=edge_valid)
             if use_kernels:
                 # the Pallas kernel sweeps the flat (n_strips, cap) layout
                 # (it pads cap to lane multiples anyway, so tiering would
                 # buy nothing)
-                buckets = gridlib.bucketize_segments(segs, plan.n_strips,
-                                                     cap)
-                cnt, dev = fused_reversal_stats(
-                    buckets, ideal=plan.ideal,
-                    strip_block=min(plan.strip_block, plan.n_strips),
-                    with_angle=want_eca, use_kernels=True)
+                with jax.named_scope(f"strips.build/axis{axis_i}"):
+                    buckets = gridlib.bucketize_segments(
+                        segs, plan.n_strips, cap)
+                with jax.named_scope(f"strips.sweep/axis{axis_i}/tier0"):
+                    cnt, dev = fused_reversal_stats(
+                        buckets, ideal=plan.ideal,
+                        strip_block=min(plan.strip_block, plan.n_strips),
+                        with_angle=want_eca, use_kernels=True)
                 stats.append((cnt, dev, buckets.overflow))
             else:
                 # occupancy-tiered sweep, as the B=1 case of the batched
@@ -535,26 +546,28 @@ def _evaluate(plan: ReadabilityPlan, pos, edges, use_kernels: bool,
                 cnt, dev, drop = _tiered_strip_stats(
                     plan, axis_i, segs1, 1, with_angle=want_eca)
                 stats.append((cnt[0], dev[0], drop[0] + segs.overflow))
-        if len(stats) == 1:
-            (ec_count, best_dev, ec_ov) = stats[0]
-            best_count = ec_count
-        else:
-            (c0, d0, o0), (c1, d1, o1) = stats
-            ec_count = jnp.maximum(c0, c1)
-            ec_ov = jnp.maximum(o0, o1)
-            # orientation with the most crossings = best-covered estimate
-            # (Table 4); strictly-greater keeps axis-0 on ties, matching
-            # the unfused path — selected on device, zero host syncs.
-            take1 = c1 > c0
-            best_count = jnp.where(take1, c1, c0)
-            best_dev = jnp.where(take1, d1, d0)
-        if want_ec:
-            out["edge_crossing"] = ec_count
-        if want_eca:
-            out["edge_crossing_angle"] = jnp.where(
-                best_count > 0,
-                1.0 - best_dev / jnp.maximum(best_count, 1), 1.0)
-            out["crossing_count_for_angle"] = best_count
+        with jax.named_scope("crossing.select"):
+            if len(stats) == 1:
+                (ec_count, best_dev, ec_ov) = stats[0]
+                best_count = ec_count
+            else:
+                (c0, d0, o0), (c1, d1, o1) = stats
+                ec_count = jnp.maximum(c0, c1)
+                ec_ov = jnp.maximum(o0, o1)
+                # orientation with the most crossings = best-covered
+                # estimate (Table 4); strictly-greater keeps axis-0 on
+                # ties, matching the unfused path — selected on device,
+                # zero host syncs.
+                take1 = c1 > c0
+                best_count = jnp.where(take1, c1, c0)
+                best_dev = jnp.where(take1, d1, d0)
+            if want_ec:
+                out["edge_crossing"] = ec_count
+            if want_eca:
+                out["edge_crossing_angle"] = jnp.where(
+                    best_count > 0,
+                    1.0 - best_dev / jnp.maximum(best_count, 1), 1.0)
+                out["crossing_count_for_angle"] = best_count
         # the strip decomposition is shared by E_c and E_ca, so its
         # dropped segments count once, as the max over orientations —
         # a starved *losing* orientation corrupts the best-orientation
@@ -625,19 +638,22 @@ def evaluate_batched_body(plan: ReadabilityPlan, batch_pos, edges,
     overflow = jnp.zeros(B, jnp.int32)
 
     if "node_occlusion" in m:
-        cnt, ov = count_occlusions_gridded_batched(
-            pos, plan.radius, plan.grid_origin, plan.grid_nx, plan.grid_ny,
-            plan.cell_cap, valid=vertex_valid,
-            cell_block=min(plan.cell_block, plan.grid_nx * plan.grid_ny),
-            cell_size=plan.grid_cell_size)
+        with jax.named_scope("occlusion"):
+            cnt, ov = count_occlusions_gridded_batched(
+                pos, plan.radius, plan.grid_origin, plan.grid_nx,
+                plan.grid_ny, plan.cell_cap, valid=vertex_valid,
+                cell_block=min(plan.cell_block, plan.grid_nx * plan.grid_ny),
+                cell_size=plan.grid_cell_size)
         overflow = overflow + ov
         out["node_occlusion"] = cnt
     if "minimum_angle" in m:
-        m_a, _ = minimum_angle_batched(pos, edges, edge_valid=edge_valid)
+        with jax.named_scope("min_angle"):
+            m_a, _ = minimum_angle_batched(pos, edges, edge_valid=edge_valid)
         out["minimum_angle"] = m_a
     if "edge_length_variation" in m:
-        out["edge_length_variation"] = edge_length_variation_batched(
-            pos, edges, edge_valid=edge_valid)
+        with jax.named_scope("edge_length"):
+            out["edge_length_variation"] = edge_length_variation_batched(
+                pos, edges, edge_valid=edge_valid)
 
     want_ec = "edge_crossing" in m
     want_eca = "edge_crossing_angle" in m
@@ -645,29 +661,31 @@ def evaluate_batched_body(plan: ReadabilityPlan, batch_pos, edges,
         stats = []
         for axis_i, (axis, (max_segments, cap)) in enumerate(
                 zip(plan.axes, plan.strip_plans)):
-            segs = gridlib.build_strip_segments_batched(
-                pos, edges, plan.n_strips, max_segments, axis=axis,
-                edge_valid=edge_valid)
+            with jax.named_scope(f"strips.build/axis{axis_i}"):
+                segs = gridlib.build_strip_segments_batched(
+                    pos, edges, plan.n_strips, max_segments, axis=axis,
+                    edge_valid=edge_valid)
             cnt, dev, drop = _tiered_strip_stats(
                 plan, axis_i, segs, B, with_angle=want_eca)
             stats.append((cnt, dev, drop + segs.overflow))
-        if len(stats) == 1:
-            (ec_count, best_dev, ec_ov) = stats[0]
-            best_count = ec_count
-        else:
-            (c0, d0, o0), (c1, d1, o1) = stats
-            ec_count = jnp.maximum(c0, c1)
-            ec_ov = jnp.maximum(o0, o1)
-            take1 = c1 > c0
-            best_count = jnp.where(take1, c1, c0)
-            best_dev = jnp.where(take1, d1, d0)
-        if want_ec:
-            out["edge_crossing"] = ec_count
-        if want_eca:
-            out["edge_crossing_angle"] = jnp.where(
-                best_count > 0,
-                1.0 - best_dev / jnp.maximum(best_count, 1), 1.0)
-            out["crossing_count_for_angle"] = best_count
+        with jax.named_scope("crossing.select"):
+            if len(stats) == 1:
+                (ec_count, best_dev, ec_ov) = stats[0]
+                best_count = ec_count
+            else:
+                (c0, d0, o0), (c1, d1, o1) = stats
+                ec_count = jnp.maximum(c0, c1)
+                ec_ov = jnp.maximum(o0, o1)
+                take1 = c1 > c0
+                best_count = jnp.where(take1, c1, c0)
+                best_dev = jnp.where(take1, d1, d0)
+            if want_ec:
+                out["edge_crossing"] = ec_count
+            if want_eca:
+                out["edge_crossing_angle"] = jnp.where(
+                    best_count > 0,
+                    1.0 - best_dev / jnp.maximum(best_count, 1), 1.0)
+                out["crossing_count_for_angle"] = best_count
         overflow = overflow + ec_ov
 
     return EngineResult(overflow=overflow, **out)

@@ -60,6 +60,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core import grid as gridlib
 from repro.core.edge_length import edge_length_variation
 from repro.core.engine import ReadabilityPlan, ReadabilityScores, _reversal_rows
@@ -443,16 +444,18 @@ def _probe_fn(plan: ReadabilityPlan, state: ResidentState, edges, n_e,
     vb, eb = pos.shape[0], edges.shape[0]
     pos2 = pos.at[moved].set(jnp.asarray(new_xy, pos.dtype), mode="drop")
     new_xyc = jnp.asarray(new_xy, pos.dtype)
-    new_cid = _cell_ids(new_xyc[:, 0], new_xyc[:, 1], plan) \
-        if "node_occlusion" in plan.metrics else jnp.zeros(
-            moved.shape, jnp.int32)
+    with jax.named_scope("occlusion"):
+        new_cid = _cell_ids(new_xyc[:, 0], new_xyc[:, 1], plan) \
+            if "node_occlusion" in plan.metrics else jnp.zeros(
+                moved.shape, jnp.int32)
     edge_valid = jnp.arange(eb, dtype=jnp.int32) < n_e
     out_axes = []
     for axis_i, axis in enumerate(plan.axes if state.strips else ()):
         st = state.strips[axis_i]
-        lo2, hi2 = _strip_domain(pos2, edges, edge_valid, axis)
-        sf, sl, nseg = _strip_spans(pos2, edges, aff, aff < eb,
-                                    st.lo, st.hi, plan.n_strips, axis)
+        with jax.named_scope(f"strips.build/axis{axis_i}"):
+            lo2, hi2 = _strip_domain(pos2, edges, edge_valid, axis)
+            sf, sl, nseg = _strip_spans(pos2, edges, aff, aff < eb,
+                                        st.lo, st.hi, plan.n_strips, axis)
         out_axes.append((lo2, hi2, sf, sl, nseg))
     return new_cid, tuple(out_axes)
 
@@ -460,10 +463,12 @@ def _probe_fn(plan: ReadabilityPlan, state: ResidentState, edges, n_e,
 def delta_probe(plan: ReadabilityPlan, state: ResidentState, edges,
                 n_e: int, moved_p, new_xy_p, aff_p):
     """Host wrapper around the probe: ONE fetch, numpy outputs."""
-    new_cid, axes = jax.device_get(_probe_fn(
+    out = _probe_fn(
         plan, state, edges, jnp.asarray(n_e, jnp.int32),
         jnp.asarray(moved_p, jnp.int32),
-        jnp.asarray(new_xy_p), jnp.asarray(aff_p, jnp.int32)))
+        jnp.asarray(new_xy_p), jnp.asarray(aff_p, jnp.int32))
+    with tracing.span("incremental.probe_fetch"):
+        new_cid, axes = jax.device_get(out)
     return {"new_cid": np.asarray(new_cid),
             "axes": tuple((np.asarray(lo2), np.asarray(hi2),
                            np.asarray(sf), np.asarray(sl), np.asarray(ns))
@@ -495,46 +500,47 @@ def _delta_fn(plan: ReadabilityPlan, state: ResidentState, edges, n_e,
     cell_vid2, cell_val2, occ2 = state.cell_vid, state.cell_valid, \
         state.occ_partial
     if "node_occlusion" in m:
-        n_cells = plan.grid_nx * plan.grid_ny
-        cap_c = plan.cell_cap
-        dc = dirty_cells
-        dc_cap = dc.shape[0]
-        dci = jnp.minimum(dc, n_cells - 1)
-        rows_vid = state.cell_vid[dci]                     # (dc, cap)
-        rows_val = state.cell_valid[dci] & (dc < n_cells)[:, None]
-        # survivors: current members minus every copy of a moved vertex
-        # (the moved pad sentinel vb hits the spare mask slot, and the
-        # vid sentinel vb rows are invalid anyway)
-        mm = jnp.zeros(vb + 1, bool).at[moved].set(True)
-        keep = rows_val & ~mm[rows_vid]
-        local = jnp.broadcast_to(
-            jnp.arange(dc_cap, dtype=jnp.int32)[:, None], (dc_cap, cap_c))
-        # movers: their new cell, located in the sorted dirty-cell list;
-        # a miss means the host dirty set was wrong -> count it lost and
-        # let the session fall back rather than under-count
-        cid2 = _cell_ids(new_xyc[:, 0], new_xyc[:, 1], plan)
-        lk = jnp.searchsorted(dc, cid2).astype(jnp.int32)
-        found = (lk < dc_cap) & (dc[jnp.minimum(lk, dc_cap - 1)] == cid2)
-        lost_cells = jnp.sum(
-            jnp.where(mv_ok & ~found, 1, 0)).astype(jnp.int32)
-        keys = jnp.concatenate([local.reshape(-1), lk])
-        vids = jnp.concatenate([rows_vid.reshape(-1), moved])
-        ok = jnp.concatenate([keep.reshape(-1), mv_ok & found])
-        nvid, in_cap, _, ovc = gridlib.gather_ragged_buckets(
-            keys[None], dc_cap,
-            np.arange(dc_cap, dtype=np.int64) * cap_c,
-            np.full(dc_cap, cap_c, np.int64), vids[None], valid=ok[None])
-        nvid = jnp.where(in_cap[0], nvid[0], vb).reshape(dc_cap, cap_c)
-        nok = in_cap[0].reshape(dc_cap, cap_c)
-        cell_vid2 = state.cell_vid.at[dc].set(nvid, mode="drop")
-        cell_val2 = state.cell_valid.at[dc].set(nok, mode="drop")
-        nbr = gridlib.neighbour_bucket_ids(plan.grid_nx, plan.grid_ny)
-        thresh = jnp.asarray((2.0 * plan.radius) ** 2, pos.dtype)
-        partial = _occ_rows(owners, cell_vid2, cell_val2, px, py,
-                            jnp.maximum(nbr, 0), nbr >= 0, thresh)
-        occ2 = state.occ_partial.at[owners].set(partial, mode="drop")
-        out["node_occlusion"] = jnp.sum(occ2)
-        overflow = overflow + ovc[0] + lost_cells
+        with jax.named_scope("occlusion"):
+            n_cells = plan.grid_nx * plan.grid_ny
+            cap_c = plan.cell_cap
+            dc = dirty_cells
+            dc_cap = dc.shape[0]
+            dci = jnp.minimum(dc, n_cells - 1)
+            rows_vid = state.cell_vid[dci]                     # (dc, cap)
+            rows_val = state.cell_valid[dci] & (dc < n_cells)[:, None]
+            # survivors: current members minus every copy of a moved vertex
+            # (the moved pad sentinel vb hits the spare mask slot, and the
+            # vid sentinel vb rows are invalid anyway)
+            mm = jnp.zeros(vb + 1, bool).at[moved].set(True)
+            keep = rows_val & ~mm[rows_vid]
+            local = jnp.broadcast_to(
+                jnp.arange(dc_cap, dtype=jnp.int32)[:, None], (dc_cap, cap_c))
+            # movers: their new cell, located in the sorted dirty-cell list;
+            # a miss means the host dirty set was wrong -> count it lost and
+            # let the session fall back rather than under-count
+            cid2 = _cell_ids(new_xyc[:, 0], new_xyc[:, 1], plan)
+            lk = jnp.searchsorted(dc, cid2).astype(jnp.int32)
+            found = (lk < dc_cap) & (dc[jnp.minimum(lk, dc_cap - 1)] == cid2)
+            lost_cells = jnp.sum(
+                jnp.where(mv_ok & ~found, 1, 0)).astype(jnp.int32)
+            keys = jnp.concatenate([local.reshape(-1), lk])
+            vids = jnp.concatenate([rows_vid.reshape(-1), moved])
+            ok = jnp.concatenate([keep.reshape(-1), mv_ok & found])
+            nvid, in_cap, _, ovc = gridlib.gather_ragged_buckets(
+                keys[None], dc_cap,
+                np.arange(dc_cap, dtype=np.int64) * cap_c,
+                np.full(dc_cap, cap_c, np.int64), vids[None], valid=ok[None])
+            nvid = jnp.where(in_cap[0], nvid[0], vb).reshape(dc_cap, cap_c)
+            nok = in_cap[0].reshape(dc_cap, cap_c)
+            cell_vid2 = state.cell_vid.at[dc].set(nvid, mode="drop")
+            cell_val2 = state.cell_valid.at[dc].set(nok, mode="drop")
+            nbr = gridlib.neighbour_bucket_ids(plan.grid_nx, plan.grid_ny)
+            thresh = jnp.asarray((2.0 * plan.radius) ** 2, pos.dtype)
+            partial = _occ_rows(owners, cell_vid2, cell_val2, px, py,
+                                jnp.maximum(nbr, 0), nbr >= 0, thresh)
+            occ2 = state.occ_partial.at[owners].set(partial, mode="drop")
+            out["node_occlusion"] = jnp.sum(occ2)
+            overflow = overflow + ovc[0] + lost_cells
 
     # -- strips: rebuild dirty strip buckets, re-sweep them -----------------
     want_ec = "edge_crossing" in m
@@ -545,95 +551,101 @@ def _delta_fn(plan: ReadabilityPlan, state: ResidentState, edges, n_e,
         ae_ok = aff < eb
         stats = []
         for axis_i, axis in enumerate(plan.axes):
-            st = state.strips[axis_i]
-            cap_s = st.eid.shape[1]
-            ds = dirty_strips[axis_i]
-            ds_cap = ds.shape[0]
-            dsi = jnp.minimum(ds, plan.n_strips - 1)
-            rows_eid = st.eid[dsi]                         # (ds, cap)
-            rows_val = st.valid[dsi] & (ds < plan.n_strips)[:, None]
-            keep = rows_val & ~me[rows_eid]
-            local = jnp.broadcast_to(
-                jnp.arange(ds_cap, dtype=jnp.int32)[:, None],
-                (ds_cap, cap_s))
-            # every new segment of an affected edge must land in a
-            # dirty strip (the host unions old + new spans); count any
-            # that don't as lost -> overflow -> fallback
-            sf, sl, nseg = _strip_spans(pos2, edges, aff, ae_ok,
-                                        st.lo, st.hi, plan.n_strips, axis)
-            in_span = (ds[None, :] >= sf[:, None]) & \
-                      (ds[None, :] <= sl[:, None])
-            cmask = ae_ok[:, None] & (ds < plan.n_strips)[None, :] & in_span
-            ckey = jnp.broadcast_to(
-                jnp.arange(ds_cap, dtype=jnp.int32)[None, :], cmask.shape)
-            ceid = jnp.broadcast_to(aff[:, None], cmask.shape)
-            lost = jnp.abs(jnp.sum(nseg)
-                           - jnp.sum(cmask.astype(jnp.int32)))
-            keys = jnp.concatenate([local.reshape(-1), ckey.reshape(-1)])
-            eids = jnp.concatenate([rows_eid.reshape(-1),
-                                    ceid.reshape(-1)])
-            ok = jnp.concatenate([keep.reshape(-1), cmask.reshape(-1)])
-            neid, in_cap, _, ovs = gridlib.gather_ragged_buckets(
-                keys[None], ds_cap,
-                np.arange(ds_cap, dtype=np.int64) * cap_s,
-                np.full(ds_cap, cap_s, np.int64), eids[None],
-                valid=ok[None])
-            neid = neid[0].reshape(ds_cap, cap_s)
-            nok = in_cap[0].reshape(ds_cap, cap_s)
-            eid2 = st.eid.at[ds].set(neid, mode="drop")
-            val2 = st.valid.at[ds].set(nok, mode="drop")
-            # values for the dirty rows, re-derived from pos2 (invalid
-            # slots carry garbage values, masked in the sweep)
-            row_strip = jnp.broadcast_to(dsi[:, None], (ds_cap, cap_s))
-            yl, yr, th, v, u = _strip_values(
-                pos2, edges, neid.reshape(-1), row_strip.reshape(-1),
-                st.lo, st.hi, plan.n_strips, axis)
-            shape = (ds_cap, cap_s)
-            cnt_r, dev_r = _reversal_rows(
-                yl.reshape(shape), yr.reshape(shape), th.reshape(shape),
-                v.reshape(shape), u.reshape(shape), nok,
-                ideal=plan.ideal, with_angle=want_eca,
-                row_block=min(plan.strip_block, ds_cap))
-            cnt2 = st.cnt.at[ds].set(cnt_r, mode="drop")
-            dev2 = st.dev.at[ds].set(dev_r, mode="drop")
+            with jax.named_scope(f"strips.build/axis{axis_i}"):
+                st = state.strips[axis_i]
+                cap_s = st.eid.shape[1]
+                ds = dirty_strips[axis_i]
+                ds_cap = ds.shape[0]
+                dsi = jnp.minimum(ds, plan.n_strips - 1)
+                rows_eid = st.eid[dsi]                         # (ds, cap)
+                rows_val = st.valid[dsi] & (ds < plan.n_strips)[:, None]
+                keep = rows_val & ~me[rows_eid]
+                local = jnp.broadcast_to(
+                    jnp.arange(ds_cap, dtype=jnp.int32)[:, None],
+                    (ds_cap, cap_s))
+                # every new segment of an affected edge must land in a
+                # dirty strip (the host unions old + new spans); count any
+                # that don't as lost -> overflow -> fallback
+                sf, sl, nseg = _strip_spans(pos2, edges, aff, ae_ok,
+                                            st.lo, st.hi, plan.n_strips, axis)
+                in_span = (ds[None, :] >= sf[:, None]) & \
+                          (ds[None, :] <= sl[:, None])
+                cmask = (ae_ok[:, None] & (ds < plan.n_strips)[None, :]
+                         & in_span)
+                ckey = jnp.broadcast_to(
+                    jnp.arange(ds_cap, dtype=jnp.int32)[None, :], cmask.shape)
+                ceid = jnp.broadcast_to(aff[:, None], cmask.shape)
+                lost = jnp.abs(jnp.sum(nseg)
+                               - jnp.sum(cmask.astype(jnp.int32)))
+                keys = jnp.concatenate([local.reshape(-1), ckey.reshape(-1)])
+                eids = jnp.concatenate([rows_eid.reshape(-1),
+                                        ceid.reshape(-1)])
+                ok = jnp.concatenate([keep.reshape(-1), cmask.reshape(-1)])
+                neid, in_cap, _, ovs = gridlib.gather_ragged_buckets(
+                    keys[None], ds_cap,
+                    np.arange(ds_cap, dtype=np.int64) * cap_s,
+                    np.full(ds_cap, cap_s, np.int64), eids[None],
+                    valid=ok[None])
+                neid = neid[0].reshape(ds_cap, cap_s)
+                nok = in_cap[0].reshape(ds_cap, cap_s)
+                eid2 = st.eid.at[ds].set(neid, mode="drop")
+                val2 = st.valid.at[ds].set(nok, mode="drop")
+                # values for the dirty rows, re-derived from pos2 (invalid
+                # slots carry garbage values, masked in the sweep)
+                row_strip = jnp.broadcast_to(dsi[:, None], (ds_cap, cap_s))
+                yl, yr, th, v, u = _strip_values(
+                    pos2, edges, neid.reshape(-1), row_strip.reshape(-1),
+                    st.lo, st.hi, plan.n_strips, axis)
+            with jax.named_scope(f"strips.sweep/axis{axis_i}/tier0"):
+                shape = (ds_cap, cap_s)
+                cnt_r, dev_r = _reversal_rows(
+                    yl.reshape(shape), yr.reshape(shape), th.reshape(shape),
+                    v.reshape(shape), u.reshape(shape), nok,
+                    ideal=plan.ideal, with_angle=want_eca,
+                    row_block=min(plan.strip_block, ds_cap))
+                cnt2 = st.cnt.at[ds].set(cnt_r, mode="drop")
+                dev2 = st.dev.at[ds].set(dev_r, mode="drop")
             stats.append((jnp.sum(cnt2), jnp.sum(dev2),
                           ovs[0] + lost.astype(jnp.int32)))
             new_strips.append(ResidentStrip(eid=eid2, valid=val2,
                                             cnt=cnt2, dev=dev2,
                                             lo=st.lo, hi=st.hi))
         # best-orientation vote, exactly as the fused engine
-        if len(stats) == 1:
-            (ec_count, best_dev, ec_ov) = stats[0]
-            best_count = ec_count
-        else:
-            (c0, d0, o0), (c1, d1, o1) = stats
-            ec_count = jnp.maximum(c0, c1)
-            ec_ov = jnp.maximum(o0, o1)
-            take1 = c1 > c0
-            best_count = jnp.where(take1, c1, c0)
-            best_dev = jnp.where(take1, d1, d0)
-        if want_ec:
-            out["edge_crossing"] = ec_count
-        if want_eca:
-            out["edge_crossing_angle"] = jnp.where(
-                best_count > 0,
-                1.0 - best_dev / jnp.maximum(best_count, 1), 1.0)
-            out["crossing_count_for_angle"] = best_count
+        with jax.named_scope("crossing.select"):
+            if len(stats) == 1:
+                (ec_count, best_dev, ec_ov) = stats[0]
+                best_count = ec_count
+            else:
+                (c0, d0, o0), (c1, d1, o1) = stats
+                ec_count = jnp.maximum(c0, c1)
+                ec_ov = jnp.maximum(o0, o1)
+                take1 = c1 > c0
+                best_count = jnp.where(take1, c1, c0)
+                best_dev = jnp.where(take1, d1, d0)
+            if want_ec:
+                out["edge_crossing"] = ec_count
+            if want_eca:
+                out["edge_crossing_angle"] = jnp.where(
+                    best_count > 0,
+                    1.0 - best_dev / jnp.maximum(best_count, 1), 1.0)
+                out["crossing_count_for_angle"] = best_count
         overflow = overflow + ec_ov
 
     # -- min angle: re-derive moved vertices + their neighbours -------------
     ma2 = state.ma_dev
     if "minimum_angle" in m:
-        dev_rows = _ma_rows(pos2, dirty_ma, state.inc_nbr, state.inc_deg)
-        ma2 = state.ma_dev.at[dirty_ma].set(dev_rows, mode="drop")
-        counted = state.inc_deg >= 1
-        out["minimum_angle"] = (1.0 - jnp.sum(ma2)
-                                / jnp.maximum(jnp.sum(counted), 1))
+        with jax.named_scope("min_angle"):
+            dev_rows = _ma_rows(pos2, dirty_ma, state.inc_nbr, state.inc_deg)
+            ma2 = state.ma_dev.at[dirty_ma].set(dev_rows, mode="drop")
+            counted = state.inc_deg >= 1
+            out["minimum_angle"] = (1.0 - jnp.sum(ma2)
+                                    / jnp.maximum(jnp.sum(counted), 1))
 
     # -- edge length variation: O(E) elementwise, recomputed in full --------
     if "edge_length_variation" in m:
-        out["edge_length_variation"] = edge_length_variation(
-            pos2, edges, edge_valid=edge_valid)
+        with jax.named_scope("edge_length"):
+            out["edge_length_variation"] = edge_length_variation(
+                pos2, edges, edge_valid=edge_valid)
 
     result = ReadabilityScores(overflow=overflow, **out)
     new_state = ResidentState(

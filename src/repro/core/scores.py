@@ -31,6 +31,8 @@ from typing import Any, NamedTuple
 import jax
 import numpy as np
 
+from repro import tracing
+
 # Metric-valued fields, in canonical order (same as engine.ALL_METRICS
 # plus the paired crossing count for E_ca).
 METRIC_FIELDS = ("node_occlusion", "minimum_angle", "edge_length_variation",
@@ -199,7 +201,8 @@ def _cast(v, to):
 def scores_from_result(res, n_vertices=None, n_edges=None
                        ) -> ReadabilityScores:
     """One (unbatched) engine result -> host scores (Python scalars)."""
-    res = jax.device_get(res)
+    with tracing.span("scores.fetch"):
+        res = jax.device_get(res)
     return ReadabilityScores(
         node_occlusion=_cast(res.node_occlusion, int),
         minimum_angle=_cast(res.minimum_angle, float),
@@ -223,7 +226,8 @@ def error_scores(error, n_vertices=None, n_edges=None) -> ReadabilityScores:
 def scores_from_batch(res, n_vertices=None, n_edges=None):
     """Split a batched result (leading B dim on every field) into a list
     of B host :class:`ReadabilityScores`; one transfer."""
-    res = jax.device_get(res)
+    with tracing.span("scores.fetch"):
+        res = jax.device_get(res)
     batch = ReadabilityScores(*res).batch_size
     if batch is None:
         raise ValueError("scores_from_batch needs a batched result; "

@@ -127,6 +127,7 @@ from collections import Counter, OrderedDict
 
 import numpy as np
 
+from repro import tracing
 from repro.core import engine
 from repro.core import incremental
 from repro.core.keys import (EvalConfig, pow2_bucket, pow2_chunks,
@@ -411,36 +412,42 @@ class EvalSession:
 
         Raises :class:`InvalidInputError` (strict mode / uninterpretable
         input) — the caller quarantines it to this request's slot."""
-        pos, edges, flags = validate_request(
-            pos, edges, mode=self.config.validation, index=index)
-        if flags:
-            self._stats["sanitized"] += 1
-        pos = np.asarray(pos, np.float32)
-        edges = np.asarray(edges, np.int32)
-        n_v, n_e = pos.shape[0], edges.shape[0]
-        vb = pow2_bucket(n_v, self.vertex_floor)
-        eb = pow2_bucket(n_e, self.edge_floor)
-        pos_p = np.full((vb, 2), PARK, np.float32)
-        pos_p[:n_v] = pos
-        edges_p = np.zeros((eb, 2), np.int32)
-        edges_p[:n_e] = edges
-        key = (topology_hash(edges, n_v), vb, eb, self.config)
+        with tracing.span("session.prepare"):
+            with tracing.span("session.validate"):
+                pos, edges, flags = validate_request(
+                    pos, edges, mode=self.config.validation, index=index)
+            if flags:
+                self._stats["sanitized"] += 1
+            with tracing.span("session.pad"):
+                pos = np.asarray(pos, np.float32)
+                edges = np.asarray(edges, np.int32)
+                n_v, n_e = pos.shape[0], edges.shape[0]
+                vb = pow2_bucket(n_v, self.vertex_floor)
+                eb = pow2_bucket(n_e, self.edge_floor)
+                pos_p = np.full((vb, 2), PARK, np.float32)
+                pos_p[:n_v] = pos
+                edges_p = np.zeros((eb, 2), np.int32)
+                edges_p[:n_e] = edges
+            with tracing.span("session.topology_hash"):
+                key = (topology_hash(edges, n_v), vb, eb, self.config)
         return key, dict(index=index, pos=pos, edges=edges, pos_p=pos_p,
                          edges_p=edges_p, n_v=n_v, n_e=n_e, flags=flags,
                          cost=vb + eb, deadline=None, cancel=None,
                          arrival=None)
 
     def _plan_for(self, key, member):
-        plan = self.plans.get(key)
-        if plan is not None:
+        with tracing.span("session.plan_lookup"):
+            plan = self.plans.get(key)
+            if plan is not None:
+                return plan
+            # tier_default=False: serving plans use the flat strip
+            # capacity unless the config says otherwise (see the module
+            # docstring)
+            plan = engine.plan_readability(
+                member["pos"], member["edges"],
+                **self.config.plan_kwargs(tier_default=False))
+            self.plans.put(key, plan)
             return plan
-        # tier_default=False: serving plans use the flat strip capacity
-        # unless the config says otherwise (see the module docstring)
-        plan = engine.plan_readability(
-            member["pos"], member["edges"],
-            **self.config.plan_kwargs(tier_default=False))
-        self.plans.put(key, plan)
-        return plan
 
     # -- dispatch -----------------------------------------------------------
 
@@ -483,10 +490,11 @@ class EvalSession:
                 if breaker.probing:
                     faults.check_probe()
                 faults.check_sharded()
-                results = [evaluate_graph_sharded(
-                    self.mesh, plan, c["pos_p"], c["edges_p"],
-                    n_valid_vertices=n_v, n_valid_edges=n_e)
-                    for c in chunk]
+                with tracing.span("engine.dispatch"):
+                    results = [evaluate_graph_sharded(
+                        self.mesh, plan, c["pos_p"], c["edges_p"],
+                        n_valid_vertices=n_v, n_valid_edges=n_e)
+                        for c in chunk]
                 breaker.record_success()
                 stats["graph_sharded_dispatches"] += len(chunk)
                 if len(chunk) > 1:
@@ -500,9 +508,10 @@ class EvalSession:
                 stats["degraded_dispatches"] += 1
                 self._log_degraded("graph_sharded", err)
         if len(chunk) == 1:
-            res = engine.evaluate_planned(
-                plan, chunk[0]["pos_p"], chunk[0]["edges_p"], n_v, n_e,
-                use_kernels=use_kernels)
+            with tracing.span("engine.dispatch"):
+                res = engine.evaluate_planned(
+                    plan, chunk[0]["pos_p"], chunk[0]["edges_p"], n_v, n_e,
+                    use_kernels=use_kernels)
             reports = [scores_from_result(res, int(n_v), int(n_e))]
         else:
             stats["coalesced"] += len(chunk)
@@ -519,9 +528,10 @@ class EvalSession:
                     if breaker.probing:
                         faults.check_probe()
                     faults.check_sharded()
-                    res = evaluate_layouts_sharded(
-                        self.mesh, plan, batch, chunk[0]["edges_p"],
-                        n_valid_vertices=n_v, n_valid_edges=n_e)
+                    with tracing.span("engine.dispatch"):
+                        res = evaluate_layouts_sharded(
+                            self.mesh, plan, batch, chunk[0]["edges_p"],
+                            n_valid_vertices=n_v, n_valid_edges=n_e)
                     breaker.record_success()
                     stats["sharded_dispatches"] += 1
                 except Exception as err:
@@ -533,9 +543,10 @@ class EvalSession:
                     self._log_degraded("sharded", err)
                     res = None
             if res is None:
-                res = engine.evaluate_layouts(
-                    plan, batch, chunk[0]["edges_p"], n_v, n_e,
-                    use_kernels=use_kernels)
+                with tracing.span("engine.dispatch"):
+                    res = engine.evaluate_layouts(
+                        plan, batch, chunk[0]["edges_p"], n_v, n_e,
+                        use_kernels=use_kernels)
         if len(chunk) > 1:
             reports = scores_from_batch(res, int(n_v), int(n_e))
         if self.mesh is not None:
@@ -593,7 +604,8 @@ class EvalSession:
         """
         timeout = self._chunk_timeout(chunk)
         if timeout is None:
-            return self._dispatch(plan, chunk)
+            with tracing.span("session.dispatch"):
+                return self._dispatch(plan, chunk)
         start = admission.clock()
         if timeout <= 0:
             raise DeadlineExceededError(
@@ -602,13 +614,17 @@ class EvalSession:
         box = {}
         done = threading.Event()
         abandoned = threading.Event()
+        # the worker's spans belong to the caller's call
+        caller = tracing.current()
 
         def work():
             stats = Counter()
             breaker = _BreakerBuffer(self.breaker)
             try:
-                box["reports"] = self._dispatch(plan, chunk, stats=stats,
-                                                breaker=breaker)
+                with tracing.carry(caller), \
+                        tracing.span("session.dispatch"):
+                    box["reports"] = self._dispatch(
+                        plan, chunk, stats=stats, breaker=breaker)
             except BaseException as err:
                 box["err"] = err
             finally:
@@ -813,6 +829,10 @@ class EvalSession:
         ``OverloadedError`` / ``DeadlineExceededError`` /
         ``CancelledError``, all in every validation mode (they are
         serving-policy outcomes, not input judgments)."""
+        with tracing.span("session.evaluate_batch"):
+            return self._evaluate_batch(requests, deadline, cancel)
+
+    def _evaluate_batch(self, requests, deadline, cancel):
         n = len(requests)
         now = (admission.clock()
                if deadline is not None or self.default_deadline is not None
@@ -978,6 +998,10 @@ class EvalSession:
         unknown ``layout_id`` and
         :class:`~repro.core.validate.InvalidInputError` for bad indices
         or non-finite coordinates (unless ``validation="off"``)."""
+        with tracing.span("session.update"):
+            return self._update(layout_id, moved_idx, new_pos)
+
+    def _update(self, layout_id, moved_idx, new_pos):
         with self._layouts_lock:
             lay = self._layouts.get(layout_id)
         if lay is None:
@@ -1013,10 +1037,11 @@ class EvalSession:
             # fallback: full re-evaluation through the serving path,
             # then re-prime the resident state from the new positions
             self._stats["delta_fallbacks"] += 1
-            lay["pos"][uniq] = new_u
-            lay["pos_p"][uniq] = new_u
-            scores = self.evaluate(lay["pos"], lay["edges"])
-            self._prime_layout(lay)
+            with tracing.span("session.update_fallback"):
+                lay["pos"][uniq] = new_u
+                lay["pos_p"][uniq] = new_u
+                scores = self.evaluate(lay["pos"], lay["edges"])
+                self._prime_layout(lay)
             return scores
 
     def _try_delta(self, lay, moved, new_xy):
@@ -1030,82 +1055,92 @@ class EvalSession:
         vb, eb = lay["vb"], lay["eb"]
         if len(moved) > thr * n_v:
             return None
-        moved_p = incremental.pad_ids(moved, vb)
-        new_xy_p = np.zeros((len(moved_p), 2), np.float32)
-        new_xy_p[:len(moved)] = new_xy
-        aff = incremental.affected_edges(lay["edges"], moved, n_v)
-        aff_p = incremental.pad_ids(aff, eb, floor=16)
-        probe = incremental.delta_probe(
-            plan_r, state, lay["edges_p"], n_e, moved_p, new_xy_p, aff_p)
+        with tracing.span("incremental.affected_edges"):
+            moved_p = incremental.pad_ids(moved, vb)
+            new_xy_p = np.zeros((len(moved_p), 2), np.float32)
+            new_xy_p[:len(moved)] = new_xy
+            aff = incremental.affected_edges(lay["edges"], moved, n_v)
+            aff_p = incremental.pad_ids(aff, eb, floor=16)
+        with tracing.span("incremental.probe"):
+            probe = incremental.delta_probe(
+                plan_r, state, lay["edges_p"], n_e, moved_p, new_xy_p,
+                aff_p)
 
         dirty_strips, k = [], len(moved)
-        for axis_i, (lo2, hi2, sfn, sln, nsn) in enumerate(probe["axes"]):
-            sfo, slo, total, lo, hi = lay["strips"][axis_i]
-            if lo2 != lo or hi2 != hi:
-                # an extremal vertex moved: every strip boundary shifts
-                return None
-            ds, old_segs, new_segs = [], 0, 0
-            for j, e in enumerate(aff_p):
-                if e >= eb:
-                    continue
-                if slo[e] >= sfo[e]:
-                    ds.extend(range(int(sfo[e]), int(slo[e]) + 1))
-                    old_segs += int(slo[e]) - int(sfo[e]) + 1
-                if sln[j] >= sfn[j]:
-                    ds.extend(range(int(sfn[j]), int(sln[j]) + 1))
-                    new_segs += int(sln[j]) - int(sfn[j]) + 1
-            max_segments = plan_r.strip_plans[axis_i][0]
-            if total - old_segs + new_segs > max_segments:
-                return None          # the delta would outgrow the plan
-            ds = np.unique(np.asarray(ds, np.int64))
-            if len(ds) > thr * plan_r.n_strips:
-                return None
-            dirty_strips.append(
-                incremental.pad_ids(ds if len(ds) else [plan_r.n_strips],
-                                    plan_r.n_strips))
+        with tracing.span("incremental.plan_strips"):
+            for axis_i, (lo2, hi2, sfn, sln, nsn) in enumerate(
+                    probe["axes"]):
+                sfo, slo, total, lo, hi = lay["strips"][axis_i]
+                if lo2 != lo or hi2 != hi:
+                    # an extremal vertex moved: every strip boundary
+                    # shifts
+                    return None
+                ds, old_segs, new_segs = [], 0, 0
+                for j, e in enumerate(aff_p):
+                    if e >= eb:
+                        continue
+                    if slo[e] >= sfo[e]:
+                        ds.extend(range(int(sfo[e]), int(slo[e]) + 1))
+                        old_segs += int(slo[e]) - int(sfo[e]) + 1
+                    if sln[j] >= sfn[j]:
+                        ds.extend(range(int(sfn[j]), int(sln[j]) + 1))
+                        new_segs += int(sln[j]) - int(sfn[j]) + 1
+                max_segments = plan_r.strip_plans[axis_i][0]
+                if total - old_segs + new_segs > max_segments:
+                    return None          # the delta would outgrow the plan
+                ds = np.unique(np.asarray(ds, np.int64))
+                if len(ds) > thr * plan_r.n_strips:
+                    return None
+                dirty_strips.append(
+                    incremental.pad_ids(ds if len(ds) else [plan_r.n_strips],
+                                        plan_r.n_strips))
 
-        dc_p = own_p = np.zeros(0, np.int32)
-        if lay["vert_cell"] is not None and \
-                "node_occlusion" in plan_r.metrics:
-            n_cells = plan_r.grid_nx * plan_r.grid_ny
-            dirty = np.unique(np.concatenate(
-                [lay["vert_cell"][moved], probe["new_cid"][:k]]))
-            if len(dirty) > thr * n_cells:
-                return None
-            dc_p = incremental.pad_ids(dirty, n_cells)
-            own_p = incremental.pad_ids(
-                incremental.owner_cells(dirty, plan_r.grid_nx,
-                                        plan_r.grid_ny),
-                n_cells, floor=16)
+        with tracing.span("incremental.plan_cells"):
+            dc_p = own_p = np.zeros(0, np.int32)
+            if lay["vert_cell"] is not None and \
+                    "node_occlusion" in plan_r.metrics:
+                n_cells = plan_r.grid_nx * plan_r.grid_ny
+                dirty = np.unique(np.concatenate(
+                    [lay["vert_cell"][moved], probe["new_cid"][:k]]))
+                if len(dirty) > thr * n_cells:
+                    return None
+                dc_p = incremental.pad_ids(dirty, n_cells)
+                own_p = incremental.pad_ids(
+                    incremental.owner_cells(dirty, plan_r.grid_nx,
+                                            plan_r.grid_ny),
+                    n_cells, floor=16)
+            # the M_a rows to re-derive: movers and their neighbours
+            dirty_ma = np.unique(np.concatenate(
+                [moved, lay["edges"][aff].reshape(-1).astype(np.int64)]))
+            dv_p = incremental.pad_ids(dirty_ma, vb, floor=16)
 
-        dirty_ma = np.unique(np.concatenate(
-            [moved, lay["edges"][aff].reshape(-1).astype(np.int64)]))
-        dv_p = incremental.pad_ids(dirty_ma, vb, floor=16)
-
-        res, new_state = incremental.evaluate_delta(
-            plan_r, state, lay["edges_p"], n_e, moved_p, new_xy_p, aff_p,
-            dc_p, own_p, tuple(dirty_strips), dv_p)
-        scores = scores_from_result(res, n_v, n_e)
+        with tracing.span("incremental.delta"):
+            res, new_state = incremental.evaluate_delta(
+                plan_r, state, lay["edges_p"], n_e, moved_p, new_xy_p,
+                aff_p, dc_p, own_p, tuple(dirty_strips), dv_p)
+            scores = scores_from_result(res, n_v, n_e)
         if scores.overflow > 0:
             # bucket overflow or a dirty-set miss during the rebuild:
             # membership equality is not guaranteed, so never commit
             return None
         # commit: device state + the host mirrors the next probe reads
-        lay["state"] = new_state
-        lay["pos"][moved] = new_xy
-        lay["pos_p"][moved] = new_xy
-        if lay["vert_cell"] is not None and \
-                "node_occlusion" in plan_r.metrics:
-            lay["vert_cell"][moved] = probe["new_cid"][:k]
-        for axis_i, (lo2, hi2, sfn, sln, nsn) in enumerate(probe["axes"]):
-            rec = lay["strips"][axis_i]
-            sfo, slo, total = rec[0], rec[1], rec[2]
-            live = aff_p < eb
-            old = np.where(slo[aff_p[live]] >= sfo[aff_p[live]],
-                           slo[aff_p[live]] - sfo[aff_p[live]] + 1, 0)
-            newn = np.where(sln[live] >= sfn[live],
-                            sln[live] - sfn[live] + 1, 0)
-            sfo[aff_p[live]] = sfn[live]
-            slo[aff_p[live]] = sln[live]
-            rec[2] = total - int(old.sum()) + int(newn.sum())
+        with tracing.span("incremental.commit"):
+            lay["state"] = new_state
+            lay["pos"][moved] = new_xy
+            lay["pos_p"][moved] = new_xy
+            if lay["vert_cell"] is not None and \
+                    "node_occlusion" in plan_r.metrics:
+                lay["vert_cell"][moved] = probe["new_cid"][:k]
+            for axis_i, (lo2, hi2, sfn, sln, nsn) in enumerate(
+                    probe["axes"]):
+                rec = lay["strips"][axis_i]
+                sfo, slo, total = rec[0], rec[1], rec[2]
+                live = aff_p < eb
+                old = np.where(slo[aff_p[live]] >= sfo[aff_p[live]],
+                               slo[aff_p[live]] - sfo[aff_p[live]] + 1, 0)
+                newn = np.where(sln[live] >= sfn[live],
+                                sln[live] - sfn[live] + 1, 0)
+                sfo[aff_p[live]] = sfn[live]
+                slo[aff_p[live]] = sln[live]
+                rec[2] = total - int(old.sum()) + int(newn.sum())
         return scores
