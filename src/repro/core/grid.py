@@ -22,6 +22,7 @@ capacities from data live at the bottom (host-side, non-jit).
 
 from __future__ import annotations
 
+import math
 from typing import NamedTuple
 
 import jax
@@ -330,6 +331,30 @@ def neighbour_bucket_ids(nx: int, ny: int):
 # vertical strips for edge crossing (paper S3.2.2)
 # ---------------------------------------------------------------------------
 
+def slot_edge_ids(offsets: jax.Array, max_segments: int) -> jax.Array:
+    """Parent edge of every segment slot: for inclusive segment offsets
+    ``(..., E)`` (a cumsum of non-negative counts, so sorted), return
+    ``(..., max_segments)`` int32 equal to
+    ``searchsorted(offsets, arange(max_segments), side="right")``.
+
+    The number of offsets ``<= slot`` is a histogram of the offsets over
+    ``[0, max_segments)`` followed by a cumsum: one scatter-add of E ones
+    and ``max_segments`` summed counts, where the binary search gathers
+    ``max_segments * log2 E`` elements in a ``while`` loop.  Offsets at or past ``max_segments``
+    land in a dropped last bin.  Exact integers, so identical to the
+    search for every slot.
+    """
+    with jax.named_scope("slot_edges"):
+        lead = offsets.shape[:-1]
+        rows = offsets.reshape(math.prod(lead), offsets.shape[-1])
+        b = jnp.arange(rows.shape[0], dtype=jnp.int32)[:, None]
+        hist = jnp.zeros((rows.shape[0], max_segments + 1), jnp.int32)
+        hist = hist.at[b, jnp.minimum(rows, max_segments)].add(
+            1, indices_are_sorted=True)
+        eid = jnp.cumsum(hist[:, :max_segments], axis=-1, dtype=jnp.int32)
+        return eid.reshape(*lead, max_segments)
+
+
 def build_strip_segments(pos: jax.Array, edges: jax.Array, n_strips: int,
                          max_segments: int, *, axis: int = 0,
                          domain=None, edge_valid=None) -> StripSegments:
@@ -377,8 +402,8 @@ def build_strip_segments(pos: jax.Array, edges: jax.Array, n_strips: int,
     total = offsets[-1]
     starts = offsets - n_seg                          # exclusive
     slot = jnp.arange(max_segments, dtype=jnp.int32)
-    eid = jnp.searchsorted(offsets, slot, side="right").astype(jnp.int32)
-    eid = jnp.minimum(eid, edges.shape[0] - 1)
+    eid = jnp.minimum(slot_edge_ids(offsets, max_segments),
+                      edges.shape[0] - 1)
     valid = slot < total
     s_local = slot - starts[eid]
     strip = s_first[eid] + s_local
@@ -412,8 +437,9 @@ def build_strip_segments_batched(pos: jax.Array, edges: jax.Array,
 
     Mirrors the single-layout function formula-for-formula (same
     elementwise op sequence, so boundary ordinates round identically and
-    integer crossing counts stay bit-compatible with the looped path);
-    only the indexing machinery grows a leading batch axis.  Strip ids
+    integer crossing counts stay bit-compatible with the looped path;
+    both find each slot's parent edge with :func:`slot_edge_ids`); only
+    the indexing machinery grows a leading batch axis.  Strip ids
     stay *per-layout* (in ``[0, n_strips]``, ``n_strips`` = trash) —
     :func:`gather_ragged_buckets` consumes the ``(B, max_segments)`` key
     rows directly, one sorted row per layout.
@@ -467,9 +493,8 @@ def build_strip_segments_batched(pos: jax.Array, edges: jax.Array,
     total = offsets[:, -1:]                          # (B, 1)
     starts = offsets - n_seg
     slot = jnp.arange(max_segments, dtype=jnp.int32)
-    eid = jax.vmap(
-        lambda off: jnp.searchsorted(off, slot, side="right"))(offsets)
-    eid = jnp.minimum(eid.astype(jnp.int32), edges.shape[0] - 1)
+    eid = jnp.minimum(slot_edge_ids(offsets, max_segments),
+                      edges.shape[0] - 1)
     valid = slot[None, :] < total
     s_local = slot[None, :] - jnp.take_along_axis(starts, eid, axis=1)
     strip = jnp.take_along_axis(s_first, eid, axis=1) + s_local

@@ -359,9 +359,8 @@ def _prime_fn(plan: ReadabilityPlan, pos, edges, n_v, n_e, inc_nbr, inc_deg):
             total = offsets[-1]
             starts = offsets - nseg
             slot = jnp.arange(max_segments, dtype=jnp.int32)
-            eid = jnp.searchsorted(offsets, slot,
-                                   side="right").astype(jnp.int32)
-            eid = jnp.minimum(eid, eb - 1)
+            eid = jnp.minimum(gridlib.slot_edge_ids(offsets, max_segments),
+                              eb - 1)
             valid = slot < total
             strip = sf[eid] + (slot - starts[eid])
             key = jnp.where(valid, strip, plan.n_strips)

@@ -112,3 +112,31 @@ def test_evaluate_layouts_compiles(one_chip):
         _sds(one_chip, edges.shape, jnp.int32)).compile()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 16 * 2 ** 30
+
+
+def test_strip_build_has_no_slot_search(one_chip):
+    """The strip build finds each segment slot's parent edge by a
+    cumulative count (``slot_edges``), not by a binary search: at a plan
+    whose long edges give thousands of segment slots per layout, the
+    only ``while`` loop left in the ``strips.build`` scope is the bucket
+    bounds search over the ``n_strips + 1`` strip ids, whose probes are
+    ``(B, n_strips + 1)``.  A slot search would carry ``(B,
+    max_segments)`` probes through ``log2 E`` gathering steps."""
+    import re
+
+    batch = 8
+    pos, edges = lattice_graph(4096, seed=2, frac_long=0.3)
+    rng = np.random.default_rng(2)
+    layouts = pos + rng.normal(0, 0.5, (batch,) + pos.shape).astype(
+        np.float32)
+    plan = engine.plan_readability(layouts, edges, n_strips=N_STRIPS)
+    assert min(s for s, _ in plan.strip_plans) > 20 * N_STRIPS
+    text = engine.evaluate_layouts.lower(
+        plan, _sds(one_chip, layouts.shape, jnp.float32),
+        _sds(one_chip, edges.shape, jnp.int32)).compile().as_text()
+    assert "/slot_edges/" in text
+    loops = [line for line in text.splitlines() if " while(" in line
+             and re.search(r'op_name="[^"]*strips\.build', line)]
+    assert loops, "the bucket bounds search should remain"
+    bounds = f"s32[{batch},{N_STRIPS + 1}]"
+    assert [line for line in loops if bounds not in line] == []
