@@ -138,9 +138,14 @@ class Evaluator:
     # -- planning -----------------------------------------------------------
 
     def plan(self, pos, edges) -> engine.ReadabilityPlan:
-        """Host-side plan for ``pos`` ((V, 2) or a (B, V, 2) batch)."""
-        return engine.plan_readability(pos, edges,
-                                       **self.config.plan_kwargs())
+        """Host-side plan for ``pos`` ((V, 2) or a (B, V, 2) batch):
+        the plan this evaluator's backend runs.  ``graph_sharded`` plans
+        flat strips (its per-device slot maps must be SPMD-uniform), so
+        a plan made here and passed back in is the plan
+        :meth:`evaluate_batch` would make for itself."""
+        tiers = self.config.backend != "graph_sharded"
+        return engine.plan_readability(
+            pos, edges, **self.config.plan_kwargs(tier_default=tiers))
 
     # -- sessions -----------------------------------------------------------
 
@@ -347,14 +352,11 @@ class Evaluator:
             # spatial partitioning is per-layout: each member IS the
             # sharded unit, so the batch axis is a host-side loop of
             # graph-sharded dispatches (one jit entry — the plan and
-            # mesh are static and shared).  Flat strips: the per-device
-            # slot maps must be SPMD-uniform, so tiers are off.
+            # mesh are static and shared).  Flat strips (see plan()).
             from repro.distributed.graph_sharded import evaluate_graph_sharded
             mesh = self._mesh()
             if plan is None:
-                plan = engine.plan_readability(
-                    batch_pos, edges,
-                    **self.config.plan_kwargs(tier_default=False))
+                plan = self.plan(batch_pos, edges)
             results = []
             for i in range(batch_pos.shape[0]):
                 with tracing.span("engine.dispatch"):

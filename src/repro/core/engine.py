@@ -746,7 +746,8 @@ def _shard_occlusion(plan: ReadabilityPlan, pos, vertex_valid, shard,
     # construction (cells_per_shard >= halo_cells), so its bucket rows
     # arrive ready-made.  Wrap-around/past-the-grid halo rows are
     # killed by the global-id mask.
-    hx, hy, hv = halo_exchange((x[:H], y[:H], bval[:H]), axis_name)
+    with jax.named_scope("graph_shard.halo"):
+        hx, hy, hv = halo_exchange((x[:H], y[:H], bval[:H]), axis_name)
     halo_gid = c0 + per_c + jnp.arange(H, dtype=jnp.int32)
     hv = hv & (halo_gid < n_cells)[:, None]
     xt = jnp.concatenate([x, hx])
@@ -837,6 +838,13 @@ def evaluate_graph_shard_body(plan: ReadabilityPlan, pos, edges, *,
     pair formulas match bitwise, and integer partial sums are
     order-independent under psum.  E_ca's float deviation sum may differ
     in summation order only.
+
+    Named scopes in the op metadata: ``graph_shard.occlusion`` (with
+    ``graph_shard.halo`` around the exchange), ``strips.build/axis{i}``
+    (the replicated build and this shard's bucketing, named as in the
+    fused path so that a change to the shared build reads the same in
+    both traces), ``graph_shard.sweep/axis{i}``, ``graph_shard.reduce``
+    (the psums), ``min_angle`` and ``edge_length``.
     """
     global _trace_count
     if isinstance(pos, jax.core.Tracer):
@@ -861,16 +869,20 @@ def evaluate_graph_shard_body(plan: ReadabilityPlan, pos, edges, *,
     overflow = jnp.zeros((), jnp.int32)
 
     if "node_occlusion" in m:
-        cnt, ov = _shard_occlusion(plan, pos, vertex_valid, shard,
-                                   axis_name)
-        out["node_occlusion"] = lax.psum(cnt, axis_name)
-        overflow = overflow + lax.psum(ov, axis_name)
+        with jax.named_scope("graph_shard.occlusion"):
+            cnt, ov = _shard_occlusion(plan, pos, vertex_valid, shard,
+                                       axis_name)
+        with jax.named_scope("graph_shard.reduce"):
+            out["node_occlusion"] = lax.psum(cnt, axis_name)
+            overflow = overflow + lax.psum(ov, axis_name)
     if "minimum_angle" in m:
-        m_a, _ = minimum_angle(pos, edges, edge_valid=edge_valid)
+        with jax.named_scope("min_angle"):
+            m_a, _ = minimum_angle(pos, edges, edge_valid=edge_valid)
         out["minimum_angle"] = m_a
     if "edge_length_variation" in m:
-        out["edge_length_variation"] = edge_length_variation(
-            pos, edges, edge_valid=edge_valid)
+        with jax.named_scope("edge_length"):
+            out["edge_length_variation"] = edge_length_variation(
+                pos, edges, edge_valid=edge_valid)
 
     want_ec = "edge_crossing" in m
     want_eca = "edge_crossing_angle" in m
@@ -878,32 +890,40 @@ def evaluate_graph_shard_body(plan: ReadabilityPlan, pos, edges, *,
         per_s = spec.strips_per_shard
         s0 = (shard * per_s).astype(jnp.int32)
         stats = []
-        for axis, (max_segments, cap) in zip(plan.axes, plan.strip_plans):
-            segs = gridlib.build_strip_segments(
-                pos, edges, plan.n_strips, max_segments, axis=axis,
-                edge_valid=edge_valid)
-            lkey = segs.strip - s0
-            # segs.valid is load-bearing beyond masking padding: the
-            # trash strip id (n_strips) can fall inside the LAST shard's
-            # local range when strips_per_shard * n_shards > n_strips
-            own = segs.valid & (lkey >= 0) & (lkey < per_s)
-            yl, yr, th, v, u, ok, _, drop = gridlib.gather_ragged_buckets(
-                lkey[None], per_s, np.arange(per_s, dtype=np.int64) * cap,
-                np.full(per_s, cap, np.int64), segs.yl[None],
-                segs.yr[None], segs.theta[None], segs.v[None],
-                segs.u[None], valid=own[None])
+        for axis_i, (axis, (max_segments, cap)) in enumerate(
+                zip(plan.axes, plan.strip_plans)):
+            with jax.named_scope(f"strips.build/axis{axis_i}"):
+                segs = gridlib.build_strip_segments(
+                    pos, edges, plan.n_strips, max_segments, axis=axis,
+                    edge_valid=edge_valid)
+                lkey = segs.strip - s0
+                # segs.valid is load-bearing beyond masking padding: the
+                # trash strip id (n_strips) can fall inside the LAST
+                # shard's local range when strips_per_shard * n_shards >
+                # n_strips
+                own = segs.valid & (lkey >= 0) & (lkey < per_s)
+                yl, yr, th, v, u, ok, _, drop = \
+                    gridlib.gather_ragged_buckets(
+                        lkey[None], per_s,
+                        np.arange(per_s, dtype=np.int64) * cap,
+                        np.full(per_s, cap, np.int64), segs.yl[None],
+                        segs.yr[None], segs.theta[None], segs.v[None],
+                        segs.u[None], valid=own[None])
             gridlib.CALL_COUNTS["reversal_sweeps"] += 1
-            rc, rd = _reversal_rows(
-                yl.reshape(per_s, cap), yr.reshape(per_s, cap),
-                th.reshape(per_s, cap), v.reshape(per_s, cap),
-                u.reshape(per_s, cap), ok.reshape(per_s, cap),
-                ideal=plan.ideal, with_angle=want_eca,
-                row_block=min(plan.strip_block, per_s))
-            cnt = lax.psum(jnp.sum(rc), axis_name)
-            dev = lax.psum(jnp.sum(rd), axis_name)
-            # segs.overflow is replicated (identical on every device):
-            # add it once, outside the psum of the per-shard drops
-            ov_ax = lax.psum(drop[0], axis_name) + segs.overflow
+            with jax.named_scope(f"graph_shard.sweep/axis{axis_i}"):
+                rc, rd = _reversal_rows(
+                    yl.reshape(per_s, cap), yr.reshape(per_s, cap),
+                    th.reshape(per_s, cap), v.reshape(per_s, cap),
+                    u.reshape(per_s, cap), ok.reshape(per_s, cap),
+                    ideal=plan.ideal, with_angle=want_eca,
+                    row_block=min(plan.strip_block, per_s))
+            with jax.named_scope("graph_shard.reduce"):
+                cnt = lax.psum(jnp.sum(rc), axis_name)
+                dev = lax.psum(jnp.sum(rd), axis_name)
+                # segs.overflow is replicated (identical on every
+                # device): add it once, outside the psum of the
+                # per-shard drops
+                ov_ax = lax.psum(drop[0], axis_name) + segs.overflow
             stats.append((cnt, dev, ov_ax))
         if len(stats) == 1:
             (ec_count, best_dev, ec_ov) = stats[0]

@@ -41,6 +41,7 @@ from jax import shard_map
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from repro import tracing
 from repro.core import engine
 from repro.core import grid as gridlib
 from repro.core.validate import BackendUnavailableError
@@ -113,9 +114,14 @@ def evaluate_graph_sharded(mesh: Mesh, plan, pos, edges, *,
     Dispatch failures surface as the typed
     :class:`~repro.core.validate.BackendUnavailableError` with the
     original error chained.
+
+    Program spans (:mod:`repro.tracing`): ``graph_sharded.inputs``
+    around the conversion and placement of ``pos`` and ``edges``, and
+    ``graph_sharded.launch`` around the jitted call.
     """
-    pos = jnp.asarray(pos, plan.dtype)
-    edges = jnp.asarray(edges, jnp.int32)
+    with tracing.span("graph_sharded.inputs"):
+        pos = jnp.asarray(pos, plan.dtype)
+        edges = jnp.asarray(edges, jnp.int32)
     if pos.ndim != 2:
         raise ValueError("evaluate_graph_sharded wants ONE (V, 2) layout "
                          f"(the graph axis is what's sharded); got shape "
@@ -125,8 +131,9 @@ def evaluate_graph_sharded(mesh: Mesh, plan, pos, edges, *,
                          f"axes {tuple(mesh.axis_names)}")
     plan = plan_with_shard_spec(plan, mesh.size)
     try:
-        return _jit_graph_sharded(plan, mesh, pos, edges,
-                                  n_valid_vertices, n_valid_edges)
+        with tracing.span("graph_sharded.launch"):
+            return _jit_graph_sharded(plan, mesh, pos, edges,
+                                      n_valid_vertices, n_valid_edges)
     except Exception as err:
         # a failed mesh dispatch (device lost, XLA runtime error) is an
         # infrastructure failure, not a caller bug: one typed error
