@@ -140,12 +140,17 @@ class Evaluator:
     def plan(self, pos, edges) -> engine.ReadabilityPlan:
         """Host-side plan for ``pos`` ((V, 2) or a (B, V, 2) batch):
         the plan this evaluator's backend runs.  ``graph_sharded`` plans
-        flat strips (its per-device slot maps must be SPMD-uniform), so
-        a plan made here and passed back in is the plan
-        :meth:`evaluate_batch` would make for itself."""
-        tiers = self.config.backend != "graph_sharded"
-        return engine.plan_readability(
-            pos, edges, **self.config.plan_kwargs(tier_default=tiers))
+        flat strips (its per-device slot maps must be SPMD-uniform) and
+        carry the mesh's shard spec with the M_a degree bound, so a plan
+        made here and passed back in is the plan :meth:`evaluate_batch`
+        would make for itself."""
+        sharded = self.config.backend == "graph_sharded"
+        plan = engine.plan_readability(
+            pos, edges, **self.config.plan_kwargs(tier_default=not sharded))
+        if sharded:
+            from repro.distributed.graph_sharded import plan_with_shard_spec
+            plan = plan_with_shard_spec(plan, self._mesh().size, edges)
+        return plan
 
     # -- sessions -----------------------------------------------------------
 

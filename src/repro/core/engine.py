@@ -110,6 +110,7 @@ from repro.core import grid as gridlib
 from repro.core import crossing_angle as _calib
 from repro.core.edge_length import (edge_length_variation,
                                     edge_length_variation_batched)
+from repro.core.geometry import TWO_PI, directed_angle
 from repro.core.min_angle import minimum_angle, minimum_angle_batched
 from repro.core.occlusion import (count_occlusions_gridded,
                                   count_occlusions_gridded_batched)
@@ -807,6 +808,94 @@ def _shard_occlusion(plan: ReadabilityPlan, pos, vertex_valid, shard,
     return jnp.sum(lax.map(block_fn, starts)), overflow[0]
 
 
+def _shard_min_angle(pos, edges, edge_valid, spec, shard):
+    """This shard's part of M_a: the deviations ``d_v`` of the vertices
+    whose run of half-edges starts in its share of the sorted topology.
+
+    The 2E half-edges ``(src, dst)`` (invalid ones sent to trash vertex
+    V, as :func:`~repro.core.min_angle.minimum_angle` does) are sorted by
+    ``src`` alone, replicated: the sort reads no positions.  Shard ``i``
+    owns the runs whose first half-edge lies in ``[i * q, (i + 1) * q)``,
+    ``q = ceil(2E / n_shards)``: its run range ``[p_i, p_{i+1})`` is cut
+    at the first run start at or after each multiple of ``q``, found by
+    counting the half-edges whose vertex precedes it (no search).  The
+    range fits a static window of ``q + deg_cap - 1`` half-edges; a range
+    that does not is counted in the returned excess, never dropped
+    silently.  In the window: one row gather of positions per endpoint,
+    the same :func:`~repro.core.geometry.directed_angle`, one two-key
+    sort by (vertex, angle), and a doubling pass that carries each run's
+    smallest in-run gap, last angle and last position back to its first
+    half-edge.  ``min`` is exact, so every ``d_v`` is the single-layout
+    path's; only the order of the final sum differs.  Returns local
+    ``(sum of d_v, counted vertices, excess)`` (pre-psum)."""
+    gridlib.CALL_COUNTS["vertex_sorts"] += 1
+    gridlib.CALL_COUNTS["vertex_range_splits"] += 1
+    V, E = pos.shape[0], edges.shape[0]
+    n = 2 * E
+    if n == 0:
+        zero = jnp.zeros((), jnp.int32)
+        return jnp.zeros((), jnp.float32), zero, zero
+    src = jnp.concatenate([edges[:, 0], edges[:, 1]]).astype(jnp.int32)
+    dst = jnp.concatenate([edges[:, 1], edges[:, 0]]).astype(jnp.int32)
+    if edge_valid is not None:
+        src = jnp.where(jnp.concatenate([edge_valid, edge_valid]), src, V)
+    s, d = lax.sort((src, dst), num_keys=1, is_stable=False)
+
+    q = -(-n // spec.n_shards)
+    W = n if spec.deg_cap <= 0 else min(n, q + spec.deg_cap - 1)
+
+    def cut(c):
+        # first run start at or after c: the half-edges whose vertex is
+        # at most the one at c - 1, trash excluded (it sorts last)
+        v = jnp.minimum(s[jnp.clip(c - 1, 0, n - 1)], V - 1)
+        return jnp.where(c <= 0, 0, jnp.sum(s <= v, dtype=jnp.int32))
+
+    lo = cut(shard * q)
+    hi = cut((shard + 1) * q)
+    excess = jnp.maximum(hi - lo - W, 0)
+    start = jnp.minimum(lo, n - W)
+    sw = lax.dynamic_slice_in_dim(s, start, W)
+    dw = lax.dynamic_slice_in_dim(d, start, W)
+    idx = start + jnp.arange(W, dtype=jnp.int32)
+    mine = (idx >= lo) & (idx < hi)
+
+    ps = pos[jnp.clip(sw, 0, V - 1)]                       # (W, 2)
+    pd = pos[jnp.clip(dw, 0, V - 1)]
+    ang = directed_angle(ps[:, 0], ps[:, 1], pd[:, 0], pd[:, 1])
+    key, a = lax.sort((jnp.where(mine, sw, V), ang), num_keys=2,
+                      is_stable=False)
+
+    # per position, over the rest of its run: the smallest in-run gap,
+    # the last angle and the last position (doubling, no scatter)
+    inf = jnp.full((1,), jnp.inf, a.dtype)
+    gap = jnp.concatenate(
+        [jnp.where(key[1:] == key[:-1], a[1:] - a[:-1], jnp.inf), inf])
+    last_a = a
+    last_i = jnp.arange(W, dtype=jnp.int32)
+    shift = 1
+    while shift < W:
+        reach = key[shift:] == key[:-shift]
+        head = W - shift
+        gap = jnp.concatenate([jnp.where(
+            reach, jnp.minimum(gap[:head], gap[shift:]), gap[:head]),
+            gap[head:]])
+        last_a = jnp.concatenate(
+            [jnp.where(reach, last_a[shift:], last_a[:head]), last_a[head:]])
+        last_i = jnp.concatenate(
+            [jnp.where(reach, last_i[shift:], last_i[:head]), last_i[head:]])
+        shift *= 2
+
+    first = (key < V) & jnp.concatenate(
+        [jnp.ones((1,), bool), key[1:] != key[:-1]])
+    deg = last_i - jnp.arange(W, dtype=jnp.int32) + 1
+    gap_min = jnp.where(deg >= 2, gap, jnp.inf)
+    wrap = TWO_PI - (last_a - a)
+    phi_min = jnp.minimum(gap_min, wrap)
+    ideal = TWO_PI / jnp.maximum(deg, 1)
+    dev = jnp.where(first, (ideal - phi_min) / ideal, 0.0)
+    return jnp.sum(dev), jnp.sum(first, dtype=jnp.int32), excess
+
+
 def evaluate_graph_shard_body(plan: ReadabilityPlan, pos, edges, *,
                               axis_name, n_valid_vertices=None,
                               n_valid_edges=None) -> EngineResult:
@@ -828,9 +917,14 @@ def evaluate_graph_shard_body(plan: ReadabilityPlan, pos, edges, *,
       one-sided halo exchange for boundary cells (:func:`_shard_occlusion`
       — the owner-cell rule counts each cross-boundary pair exactly
       once);
-    * **M_a / M_l**: O(E log E) / O(E) replicated — cheaper than any
-      collective (the same call the single-host path makes, so floats
-      are bit-identical).
+    * **M_a**: split by vertex ownership (:func:`_shard_min_angle`):
+      the topology sort of the 2E half-edges is replicated, then each
+      shard gathers, sorts and reduces only the runs of the vertices it
+      owns, and ONE psum of ``(sum of d_v, counted vertices)`` joins
+      them.  Each ``d_v`` is bit-identical to the single-host path's;
+      the final float sum differs in order only;
+    * **M_l**: O(E) replicated (the same call the single-host path
+      makes, so it is bit-identical).
 
     Integer metrics are bit-identical to the single-host fused path under
     the same (flat-capacity) plan and invariant to the shard count: kept
@@ -844,7 +938,8 @@ def evaluate_graph_shard_body(plan: ReadabilityPlan, pos, edges, *,
     (the replicated build and this shard's bucketing, named as in the
     fused path so that a change to the shared build reads the same in
     both traces), ``graph_shard.sweep/axis{i}``, ``graph_shard.reduce``
-    (the psums), ``min_angle`` and ``edge_length``.
+    (the psums), ``min_angle`` (this shard's share of M_a) and
+    ``edge_length``.
     """
     global _trace_count
     if isinstance(pos, jax.core.Tracer):
@@ -877,8 +972,13 @@ def evaluate_graph_shard_body(plan: ReadabilityPlan, pos, edges, *,
             overflow = overflow + lax.psum(ov, axis_name)
     if "minimum_angle" in m:
         with jax.named_scope("min_angle"):
-            m_a, _ = minimum_angle(pos, edges, edge_valid=edge_valid)
-        out["minimum_angle"] = m_a
+            dev, n_counted, excess = _shard_min_angle(pos, edges,
+                                                      edge_valid, spec, shard)
+        with jax.named_scope("graph_shard.reduce"):
+            dev, n_counted, excess = lax.psum((dev, n_counted, excess),
+                                              axis_name)
+        out["minimum_angle"] = 1.0 - dev / jnp.maximum(n_counted, 1)
+        overflow = overflow + excess
     if "edge_length_variation" in m:
         with jax.named_scope("edge_length"):
             out["edge_length_variation"] = edge_length_variation(
@@ -1036,6 +1136,15 @@ def replan_on_overflow(plan: ReadabilityPlan, pos, edges, result,
             (max(f_ms, gridlib._round_up(int(o_ms * growth), 128)),
              tiers[0][0]))
         strip_tiers.append(tiers)
+    # a graph-sharded plan's M_a window regrows the same way: the fresh
+    # degree bound, floored at ``growth`` x the old one (the ranges are
+    # re-derived from the grid at dispatch)
+    graph_shard = plan.graph_shard
+    if graph_shard is not None:
+        graph_shard = graph_shard._replace(deg_cap=max(
+            gridlib.plan_degree_cap(edges),
+            gridlib._next_pow2(int(graph_shard.deg_cap * growth), floor=2)))
     return dataclasses.replace(fresh, cell_cap=cell_cap,
                                strip_plans=tuple(strip_plans),
-                               strip_tiers=tuple(strip_tiers))
+                               strip_tiers=tuple(strip_tiers),
+                               graph_shard=graph_shard)

@@ -53,8 +53,12 @@ FORWARD_NEIGHBOURHOOD = ((1, 0), (-1, 1), (0, 1), (1, 1))
 # skips the vertex-key sort).  ``halo_exchanges`` certifies the
 # graph-sharded path's collective budget: exactly ONE boundary-cell
 # exchange per evaluation, zero for strip-only metric subsets.
+# ``vertex_range_splits`` certifies that the graph-sharded path scores
+# M_a by vertex range (each device its own runs of half-edges, one psum)
+# and that the single-host paths never take that stage.
 CALL_COUNTS = {"strip_builds": 0, "reversal_sweeps": 0, "cell_builds": 0,
-               "vertex_sorts": 0, "halo_exchanges": 0}
+               "vertex_sorts": 0, "halo_exchanges": 0,
+               "vertex_range_splits": 0}
 
 
 def reset_call_counts():
@@ -119,14 +123,23 @@ class GraphShardSpec(NamedTuple):
     range — guaranteed to be a prefix of the next shard's owned range
     because :func:`plan_graph_shards` forces ``cells_per_shard >=
     halo_cells`` — so the forward-neighbourhood sweep needs exactly one
-    one-sided exchange.  Plain ints: hashable plan data (part of
-    :class:`repro.core.engine.ReadabilityPlan`, so a mesh-size change is
-    a retrace, never a silent reuse)."""
+    one-sided exchange.
+
+    M_a splits by vertex ownership: shard ``i`` scores the vertices whose
+    run of half-edges (sorted by source vertex) starts in
+    ``[i * q, (i + 1) * q)``, ``q = ceil(2E / n_shards)``.  Such a run
+    range holds at most ``q + deg_cap - 1`` half-edges, the static window
+    each shard reads.  ``deg_cap`` is a power of two at least the graph's
+    largest degree (:func:`plan_degree_cap`); 0 means not planned, and
+    the window is then all 2E half-edges.  Plain ints: hashable plan data
+    (part of :class:`repro.core.engine.ReadabilityPlan`, so a mesh-size
+    or degree-bound change is a retrace, never a silent reuse)."""
 
     n_shards: int
     strips_per_shard: int
     cells_per_shard: int
     halo_cells: int
+    deg_cap: int = 0
 
 
 class SegmentBuckets(NamedTuple):
@@ -641,7 +654,7 @@ def plan_strips(pos, edges, n_strips: int, pad: float = 1.25,
 
 
 def plan_graph_shards(n_strips: int, nx: int, ny: int,
-                      n_shards: int) -> GraphShardSpec:
+                      n_shards: int, deg_cap: int = 0) -> GraphShardSpec:
     """Partition strips and grid cells contiguously over ``n_shards``.
 
     ``cells_per_shard`` is clamped to at least ``nx + 1`` (the halo
@@ -656,7 +669,23 @@ def plan_graph_shards(n_strips: int, nx: int, ny: int,
     strips_per = -(-int(n_strips) // n_shards)
     cells_per = max(-(-(int(nx) * int(ny)) // n_shards), halo)
     return GraphShardSpec(n_shards=n_shards, strips_per_shard=strips_per,
-                          cells_per_shard=cells_per, halo_cells=halo)
+                          cells_per_shard=cells_per, halo_cells=halo,
+                          deg_cap=int(deg_cap))
+
+
+def plan_degree_cap(edges, n_valid_edges=None) -> int:
+    """The power of two (floor 2) at least the largest vertex degree of
+    ``edges[:n_valid_edges]``: a self-loop counts twice, as its two
+    half-edges do.  Host side; sizes the graph-sharded M_a window
+    (:class:`GraphShardSpec`)."""
+    import numpy as np
+
+    e = np.asarray(edges)
+    if n_valid_edges is not None:
+        e = e[:int(n_valid_edges)]
+    e = e.reshape(-1)
+    top = int(np.bincount(e).max()) if e.size else 0
+    return _next_pow2(top, floor=2)
 
 
 def _next_pow2(n: int, floor: int = 8) -> int:

@@ -15,7 +15,10 @@ decompositions of one layout contiguously across a 1-D mesh
   ONE one-sided halo exchange
   (:func:`repro.distributed.collectives.halo_exchange`) for boundary
   cells; the owner-cell rule counts each cross-boundary pair once;
-* **M_a / M_l**: replicated (cheaper than any collective).
+* **M_a**: split by vertex ownership — the topology sort is
+  replicated, each device scores the vertex runs that start in its share
+  of it, and one psum joins the partial sums;
+* **M_l**: replicated (an O(E) pass, cheaper than any collective).
 
 Inputs are fully replicated (coordinates are O(V) — what's sharded is
 the O(pairs) sweep *work*, which is what dominates at scale); outputs
@@ -47,16 +50,24 @@ from repro.core import grid as gridlib
 from repro.core.validate import BackendUnavailableError
 
 
-def plan_with_shard_spec(plan, n_shards: int):
+def plan_with_shard_spec(plan, n_shards: int, edges=None,
+                         n_valid_edges=None):
     """``plan`` with its ``graph_shard`` spec matching ``n_shards``.
 
     Derives the per-device strip/cell ranges from the plan's own grid
     geometry, so a replanned (grown) plan re-derives fresh ranges — the
-    spec can never go stale relative to the capacities.  Returns the
-    plan unchanged when the spec already matches (plan equality keeps
-    the jit cache warm)."""
+    spec can never go stale relative to the capacities.  The M_a degree
+    bound (``GraphShardSpec.deg_cap``) is kept from the plan's spec;
+    a plan without one takes it from ``edges[:n_valid_edges]`` on the
+    host (:func:`~repro.core.grid.plan_degree_cap`), so planning once
+    (``Evaluator.plan``, the session's plan cache) keeps that pass out
+    of every call.  Returns the plan unchanged when the spec already
+    matches (plan equality keeps the jit cache warm)."""
+    deg_cap = plan.graph_shard.deg_cap if plan.graph_shard is not None else 0
+    if deg_cap <= 0 and edges is not None:
+        deg_cap = gridlib.plan_degree_cap(edges, n_valid_edges)
     spec = gridlib.plan_graph_shards(plan.n_strips, plan.grid_nx,
-                                     plan.grid_ny, n_shards)
+                                     plan.grid_ny, n_shards, deg_cap)
     if plan.graph_shard == spec:
         return plan
     return dataclasses.replace(plan, graph_shard=spec)
@@ -119,6 +130,7 @@ def evaluate_graph_sharded(mesh: Mesh, plan, pos, edges, *,
     around the conversion and placement of ``pos`` and ``edges``, and
     ``graph_sharded.launch`` around the jitted call.
     """
+    host_edges = edges
     with tracing.span("graph_sharded.inputs"):
         pos = jnp.asarray(pos, plan.dtype)
         edges = jnp.asarray(edges, jnp.int32)
@@ -129,7 +141,7 @@ def evaluate_graph_sharded(mesh: Mesh, plan, pos, edges, *,
     if len(mesh.axis_names) != 1:
         raise ValueError("evaluate_graph_sharded wants a 1-D mesh; got "
                          f"axes {tuple(mesh.axis_names)}")
-    plan = plan_with_shard_spec(plan, mesh.size)
+    plan = plan_with_shard_spec(plan, mesh.size, host_edges, n_valid_edges)
     try:
         with tracing.span("graph_sharded.launch"):
             return _jit_graph_sharded(plan, mesh, pos, edges,

@@ -446,6 +446,14 @@ class EvalSession:
             plan = engine.plan_readability(
                 member["pos"], member["edges"],
                 **self.config.plan_kwargs(tier_default=False))
+            if (self.config.backend == "graph_sharded"
+                    and self.mesh is not None):
+                # the M_a degree bound from the natural edges, once per
+                # topology rather than once per dispatch
+                from repro.distributed.graph_sharded import \
+                    plan_with_shard_spec
+                plan = plan_with_shard_spec(plan, self.mesh.size,
+                                            member["edges"])
             self.plans.put(key, plan)
             return plan
 

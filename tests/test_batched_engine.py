@@ -176,7 +176,8 @@ def test_batched_work_shape():
     jax.block_until_ready(evaluate_layouts(plan, batch, edges))
     assert gridlib.CALL_COUNTS == {"strip_builds": 2, "reversal_sweeps": 2,
                                    "cell_builds": 1, "vertex_sorts": 1,
-                                   "halo_exchanges": 0}
+                                   "halo_exchanges": 0,
+                                   "vertex_range_splits": 0}
 
 
 def test_gather_ragged_matches_dense_on_uniform_caps():

@@ -217,14 +217,16 @@ def test_fused_sweep_counts():
                             orientation="both")
     assert gridlib.CALL_COUNTS == {"strip_builds": 4, "reversal_sweeps": 4,
                                    "cell_builds": 0, "vertex_sorts": 0,
-                                   "halo_exchanges": 0}
+                                   "halo_exchanges": 0,
+                                   "vertex_range_splits": 0}
 
     plan = plan_readability(pos, edges, radius=RADIUS, n_strips=48)
     gridlib.reset_call_counts()
     jax.block_until_ready(evaluate_planned(plan, pos, edges))
     assert gridlib.CALL_COUNTS == {"strip_builds": 2, "reversal_sweeps": 2,
                                    "cell_builds": 1, "vertex_sorts": 1,
-                                   "halo_exchanges": 0}
+                                   "halo_exchanges": 0,
+                                   "vertex_range_splits": 0}
 
 
 def test_use_kernels_parity():

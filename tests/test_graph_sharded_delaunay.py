@@ -14,6 +14,7 @@ makes for itself, so a caller that plans once reuses one jit entry, and
 the lowered program must name every scope of the graph-sharded body.
 """
 
+import dataclasses
 import json
 import os
 import subprocess
@@ -163,7 +164,7 @@ def test_the_lowered_program_names_every_graph_shard_scope(runs):
 
 def test_the_fused_plan_keeps_its_tiers(monkeypatch):
     from repro.api import EvalConfig, Evaluator
-    from repro.core import engine
+    from repro.core import engine, grid
 
     monkeypatch.syspath_prepend(BENCH)
     import graphs
@@ -178,8 +179,11 @@ def test_the_fused_plan_keeps_its_tiers(monkeypatch):
     assert all(len(t) > 0 for t in plan.strip_tiers)
     sharded = EvalConfig(**kw, backend="graph_sharded")
     flat = Evaluator(sharded).plan(pos, edges)
-    assert flat == engine.plan_readability(
-        pos, edges, **sharded.plan_kwargs(tier_default=False))
+    # the sharded plan adds only its shard spec, with the M_a degree bound
+    assert dataclasses.replace(flat, graph_shard=None) == \
+        engine.plan_readability(pos, edges,
+                                **sharded.plan_kwargs(tier_default=False))
+    assert flat.graph_shard.deg_cap == grid.plan_degree_cap(edges)
     assert flat.strip_plans == plan.strip_plans
 
 

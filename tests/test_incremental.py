@@ -42,7 +42,8 @@ FLOAT_FIELDS = ("minimum_angle", "edge_length_variation",
                 "edge_crossing_angle")
 
 IDLE_COUNTS = {"strip_builds": 0, "reversal_sweeps": 0, "cell_builds": 0,
-               "vertex_sorts": 0, "halo_exchanges": 0}
+               "vertex_sorts": 0, "halo_exchanges": 0,
+               "vertex_range_splits": 0}
 
 
 def make_session(**kw):
