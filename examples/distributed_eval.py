@@ -57,8 +57,11 @@ print(f"sharded exact E_c = {cross}  ({time.time() - t0:.2f}s)")
 # the planner — undersized budgets silently drop segments)
 n_strips = 256
 max_segments, cap = gridlib.plan_strips(pos, edges, n_strips)
-segs = gridlib.build_strip_segments(pos, edges, n_strips, max_segments)
-buckets = gridlib.bucketize_segments(segs, n_strips, cap=cap)
+segs = gridlib.build_strip_segments_batched(jnp.asarray(pos)[None],
+                                           jnp.asarray(edges), n_strips,
+                                           max_segments)
+buckets = gridlib.bucketize_segments(
+    jax.tree_util.tree_map(lambda a: a[0], segs), n_strips, cap=cap)
 (enh,) = sharded_reversal_stats(mesh, buckets)
 assert int(buckets.overflow) == 0, "segment budget overflow"
 err = abs(int(enh) - cross) / max(cross, 1)

@@ -330,7 +330,7 @@ class Evaluator:
                 plan = self.plan(batch_pos, edges)
             with tracing.span("engine.dispatch"):
                 if backend == "eager":
-                    res = engine._evaluate_batched(
+                    res = engine.evaluate_batched_body(
                         plan, pos_p, edges_p, np.int32(n_v), np.int32(n_e))
                 else:
                     res = engine.evaluate_layouts(
@@ -376,7 +376,7 @@ class Evaluator:
             plan = self.plan(batch_pos, edges)
         with tracing.span("engine.dispatch"):
             if backend == "eager":
-                res = engine._evaluate_batched(plan, batch_pos, edges)
+                res = engine.evaluate_batched_body(plan, batch_pos, edges)
             else:
                 res = engine.evaluate_layouts(
                     plan, batch_pos, edges,

@@ -23,6 +23,6 @@ from repro.core.metrics import (ALL_METRICS, ReadabilityReport,  # noqa: F401
 from repro.core.min_angle import minimum_angle  # noqa: F401
 from repro.core.occlusion import (count_occlusions_enhanced,  # noqa: F401
                                   count_occlusions_exact,
-                                  count_occlusions_gridded)
+                                  count_occlusions_gridded_batched)
 from repro.core.scores import (ReadabilityScores,  # noqa: F401
                                scores_from_batch, scores_from_result)

@@ -91,12 +91,13 @@ def bucket_reversal_stats(buckets: gridlib.SegmentBuckets, *,
 
 def count_crossings_strips(pos, edges, n_strips: int, max_segments: int,
                            cap: int, *, axis: int = 0, edge_valid=None,
-                           strip_block: int = 256, domain=None):
+                           strip_block: int = 256):
     """Enhanced E_c for one strip orientation (jit-friendly, static sizes)."""
-    segs = gridlib.build_strip_segments(pos, edges, n_strips, max_segments,
-                                        axis=axis, domain=domain,
-                                        edge_valid=edge_valid)
-    buckets = gridlib.bucketize_segments(segs, n_strips, cap)
+    segs = gridlib.build_strip_segments_batched(
+        jnp.asarray(pos)[None], edges, n_strips, max_segments, axis=axis,
+        edge_valid=edge_valid)
+    buckets = gridlib.bucketize_segments(
+        jax.tree_util.tree_map(lambda a: a[0], segs), n_strips, cap)
     (count,) = bucket_reversal_stats(buckets, strip_block=strip_block)
     return count, buckets.overflow
 

@@ -15,6 +15,7 @@ asymptotic work per strip as the dense crossing count it rides on.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -77,13 +78,13 @@ def crossing_angle_exact(pos, edges, *, ideal=DEFAULT_IDEAL, block: int = 512,
 
 def crossing_angle_strips(pos, edges, n_strips: int, max_segments: int,
                           cap: int, *, ideal=DEFAULT_IDEAL, axis: int = 0,
-                          edge_valid=None, strip_block: int = 256,
-                          domain=None):
+                          edge_valid=None, strip_block: int = 256):
     """Enhanced E_ca for one orientation (jit-friendly, static sizes)."""
-    segs = gridlib.build_strip_segments(pos, edges, n_strips, max_segments,
-                                        axis=axis, domain=domain,
-                                        edge_valid=edge_valid)
-    buckets = gridlib.bucketize_segments(segs, n_strips, cap)
+    segs = gridlib.build_strip_segments_batched(
+        jnp.asarray(pos)[None], edges, n_strips, max_segments, axis=axis,
+        edge_valid=edge_valid)
+    buckets = gridlib.bucketize_segments(
+        jax.tree_util.tree_map(lambda a: a[0], segs), n_strips, cap)
     count, dev_sum = bucket_reversal_stats(buckets, strip_block=strip_block,
                                            ideal_angle=ideal)
     e_ca = jnp.where(count > 0, 1.0 - dev_sum / jnp.maximum(count, 1), 1.0)

@@ -22,8 +22,10 @@ This module splits the work:
   drifts far enough to overflow them, the ``overflow`` counter in the
   result says so — replan then.
 
-* **Evaluate** (:func:`evaluate_planned`, jitted, many times): all five
-  metrics in ONE traced program with shared decompositions.  Data flow::
+* **Evaluate** (:func:`evaluate_batched_body`, jitted as
+  :func:`evaluate_layouts` for a batch and as :func:`evaluate_planned`
+  for one layout at ``B=1``): all five metrics in ONE traced program with
+  shared decompositions.  Data flow::
 
       pos ──> cell buckets ────────────────────────────> N_c        (build x1)
       pos ──> strip segments ──> per-strip buckets ──┐
@@ -52,16 +54,16 @@ This module splits the work:
   composite-key sort and materializes buckets by pure gathers
   (:func:`repro.core.grid.gather_ragged_buckets` — no scatter at all),
   and ONE reversal sweep per orientation covers the
-  ``(B * n_strips, cap)`` rows.  Integer metrics are bit-identical to
-  looping the single-layout path.
+  ``(B * n_strips, cap)`` rows.  A single layout is the same program at
+  ``B=1``, so integer metrics are bit-identical whatever the batch.
 
 * **Occupancy tiers**: real layouts are skewed — power-law graphs
   concentrate segments in few strips — and a flat per-strip capacity
   makes every strip pay the fullest strip's dense ``cap^2`` pair tile.
   The plan sorts strips by planned occupancy into <= 3 pow2 capacity
   tiers (:func:`repro.core.grid.plan_strip_tiers`; tier boundaries are
-  host-side plan data, so shapes stay static) and both the single-layout
-  and batched paths sweep each tier at its own capacity via the ragged
+  host-side plan data, so shapes stay static) and the program sweeps
+  each tier at its own capacity via the ragged
   one-sort gather bucketing (:func:`repro.core.grid.gather_ragged_buckets`).
   :func:`fused_reversal_block` stays the single source of truth for the
   reversal formula; tiering only changes the float summation *order* of
@@ -100,6 +102,7 @@ offending layout, floored at ``growth`` x the old ones) for a retry.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -111,9 +114,8 @@ from repro.core import crossing_angle as _calib
 from repro.core.edge_length import (edge_length_variation,
                                     edge_length_variation_batched)
 from repro.core.geometry import TWO_PI, directed_angle
-from repro.core.min_angle import minimum_angle, minimum_angle_batched
-from repro.core.occlusion import (count_occlusions_gridded,
-                                  count_occlusions_gridded_batched)
+from repro.core.min_angle import minimum_angle_batched
+from repro.core.occlusion import count_occlusions_gridded_batched
 from repro.core.scores import ReadabilityScores
 
 # The five paper metrics (re-exported by repro.core.metrics).
@@ -291,8 +293,7 @@ def fused_reversal_stats(buckets: gridlib.SegmentBuckets, *, ideal=1.0,
 
 
 # ---------------------------------------------------------------------------
-# occupancy-tiered sweep (ragged per-strip capacities, shared by the
-# single-layout and natively batched paths)
+# occupancy-tiered sweep (ragged per-strip capacities)
 # ---------------------------------------------------------------------------
 
 def _reversal_rows(yl, yr, th, v, u, ok, *, ideal, with_angle: bool,
@@ -368,10 +369,9 @@ def _tiered_strip_stats(plan: "ReadabilityPlan", axis_i: int, segs, B: int,
     """One-sort gather bucketing + occupancy-tiered reversal sweep.
 
     ``segs`` is a batched :class:`~repro.core.grid.StripSegments` with
-    ``(B, max_segments)`` fields (``B=1`` for the single-layout path —
-    the batched and looped programs share this code, which is what makes
-    their integer metrics bit-identical).  The whole batch is grouped by
-    ONE composite-key sort and materialized by gathers
+    ``(B, max_segments)`` fields (``B=1`` for a single layout).  The
+    whole batch is grouped by ONE composite-key sort and materialized by
+    gathers
     (:func:`~repro.core.grid.gather_ragged_buckets`; no scatter, no
     vmap), and each capacity tier is swept at its own ``cap_t^2`` pair
     tile instead of every strip paying the fullest strip's.  Returns
@@ -466,159 +466,95 @@ def plan_readability(pos, edges, *, radius: float = 0.5, ideal_angle=None,
 # fused evaluation (one traced program, all metrics)
 # ---------------------------------------------------------------------------
 
-def _evaluate(plan: ReadabilityPlan, pos, edges, use_kernels: bool,
-              n_valid_vertices=None, n_valid_edges=None) -> EngineResult:
-    global _trace_count
-    if isinstance(pos, jax.core.Tracer):
-        _trace_count += 1
-    pos = jnp.asarray(pos, plan.dtype)
-    edges = jnp.asarray(edges, jnp.int32)
-    vertex_valid = None
-    if n_valid_vertices is not None:
-        vertex_valid = (jnp.arange(pos.shape[0], dtype=jnp.int32)
-                        < jnp.asarray(n_valid_vertices, jnp.int32))
-    edge_valid = None
-    if n_valid_edges is not None:
-        edge_valid = (jnp.arange(edges.shape[0], dtype=jnp.int32)
-                      < jnp.asarray(n_valid_edges, jnp.int32))
-    m = plan.metrics
+def _valid_masks(n_vertices: int, n_edges: int, n_valid_vertices=None,
+                 n_valid_edges=None):
+    """``(vertex_valid, edge_valid)``: ``arange(n) < n_valid`` masks from
+    the traced ``n_valid_*`` scalars of the padding contract, ``None``
+    where a scalar is not given."""
+    def mask(n, n_valid):
+        if n_valid is None:
+            return None
+        return (jnp.arange(n, dtype=jnp.int32)
+                < jnp.asarray(n_valid, jnp.int32))
+    return (mask(n_vertices, n_valid_vertices),
+            mask(n_edges, n_valid_edges))
+
+
+def _select_orientation(stats, want_ec: bool, want_eca: bool):
+    """Best-orientation vote over per-orientation ``(count, deviation_sum,
+    dropped)`` stats -> ``(scores, overflow)``.
+
+    E_c is the larger count; E_ca comes from the orientation with the
+    most crossings (the best-covered estimate, Table 4), strictly-greater
+    keeping axis 0 on ties — selected on device, zero host syncs.  The
+    strip decomposition is shared by E_c and E_ca, so its dropped
+    segments count once, as the max over orientations: a starved
+    *losing* orientation corrupts the vote too, so its drops must still
+    trip the replan signal.  Every body that sweeps strips (batched,
+    graph-sharded, delta, soft) votes here."""
     out = {}
-    overflow = jnp.zeros((), jnp.int32)
-
-    if "node_occlusion" in m:
-        with jax.named_scope("occlusion"):
-            if use_kernels:
-                # exact tiled pairwise Pallas kernel: same count as the
-                # grid (paper Table 3: enhanced N_c has 0% error), no
-                # capacities to overflow
-                from repro.kernels.ops import occlusion_count_op
-                cnt = occlusion_count_op(pos, plan.radius,
-                                         valid=vertex_valid)
-            else:
-                cnt, ov = count_occlusions_gridded(
-                    pos, plan.radius, plan.grid_origin, plan.grid_nx,
-                    plan.grid_ny, plan.cell_cap, valid=vertex_valid,
-                    cell_block=min(plan.cell_block,
-                                   plan.grid_nx * plan.grid_ny),
-                    cell_size=plan.grid_cell_size)
-                overflow = overflow + ov
-        out["node_occlusion"] = cnt
-    if "minimum_angle" in m:
-        with jax.named_scope("min_angle"):
-            m_a, _ = minimum_angle(pos, edges, edge_valid=edge_valid)
-        out["minimum_angle"] = m_a
-    if "edge_length_variation" in m:
-        with jax.named_scope("edge_length"):
-            out["edge_length_variation"] = edge_length_variation(
-                pos, edges, edge_valid=edge_valid)
-
-    want_ec = "edge_crossing" in m
-    want_eca = "edge_crossing_angle" in m
-    if want_ec or want_eca:
-        stats = []
-        for axis_i, (axis, (max_segments, cap)) in enumerate(
-                zip(plan.axes, plan.strip_plans)):
-            # strip build + bucketing happen ONCE per orientation; the one
-            # fused sweep serves both E_c and E_ca
-            with jax.named_scope(f"strips.build/axis{axis_i}"):
-                segs = gridlib.build_strip_segments(
-                    pos, edges, plan.n_strips, max_segments, axis=axis,
-                    edge_valid=edge_valid)
-            if use_kernels:
-                # the Pallas kernel sweeps the flat (n_strips, cap) layout
-                # (it pads cap to lane multiples anyway, so tiering would
-                # buy nothing)
-                with jax.named_scope(f"strips.build/axis{axis_i}"):
-                    buckets = gridlib.bucketize_segments(
-                        segs, plan.n_strips, cap)
-                with jax.named_scope(f"strips.sweep/axis{axis_i}/tier0"):
-                    cnt, dev = fused_reversal_stats(
-                        buckets, ideal=plan.ideal,
-                        strip_block=min(plan.strip_block, plan.n_strips),
-                        with_angle=want_eca, use_kernels=True)
-                stats.append((cnt, dev, buckets.overflow))
-            else:
-                # occupancy-tiered sweep, as the B=1 case of the batched
-                # program (shared code keeps looped == batched bit-exact)
-                segs1 = segs._replace(
-                    strip=segs.strip[None], yl=segs.yl[None],
-                    yr=segs.yr[None], theta=segs.theta[None],
-                    v=segs.v[None], u=segs.u[None], valid=segs.valid[None])
-                cnt, dev, drop = _tiered_strip_stats(
-                    plan, axis_i, segs1, 1, with_angle=want_eca)
-                stats.append((cnt[0], dev[0], drop[0] + segs.overflow))
-        with jax.named_scope("crossing.select"):
-            if len(stats) == 1:
-                (ec_count, best_dev, ec_ov) = stats[0]
-                best_count = ec_count
-            else:
-                (c0, d0, o0), (c1, d1, o1) = stats
-                ec_count = jnp.maximum(c0, c1)
-                ec_ov = jnp.maximum(o0, o1)
-                # orientation with the most crossings = best-covered
-                # estimate (Table 4); strictly-greater keeps axis-0 on
-                # ties, matching the unfused path — selected on device,
-                # zero host syncs.
-                take1 = c1 > c0
-                best_count = jnp.where(take1, c1, c0)
-                best_dev = jnp.where(take1, d1, d0)
-            if want_ec:
-                out["edge_crossing"] = ec_count
-            if want_eca:
-                out["edge_crossing_angle"] = jnp.where(
-                    best_count > 0,
-                    1.0 - best_dev / jnp.maximum(best_count, 1), 1.0)
-                out["crossing_count_for_angle"] = best_count
-        # the strip decomposition is shared by E_c and E_ca, so its
-        # dropped segments count once, as the max over orientations —
-        # a starved *losing* orientation corrupts the best-orientation
-        # vote too, so its drops must still trip the replan signal
-        overflow = overflow + ec_ov
-
-    return EngineResult(overflow=overflow, **out)
+    with jax.named_scope("crossing.select"):
+        if len(stats) == 1:
+            (ec_count, best_dev, ec_ov) = stats[0]
+            best_count = ec_count
+        else:
+            (c0, d0, o0), (c1, d1, o1) = stats
+            ec_count = jnp.maximum(c0, c1)
+            ec_ov = jnp.maximum(o0, o1)
+            take1 = c1 > c0
+            best_count = jnp.where(take1, c1, c0)
+            best_dev = jnp.where(take1, d1, d0)
+        if want_ec:
+            out["edge_crossing"] = ec_count
+        if want_eca:
+            out["edge_crossing_angle"] = jnp.where(
+                best_count > 0,
+                1.0 - best_dev / jnp.maximum(best_count, 1), 1.0)
+            out["crossing_count_for_angle"] = best_count
+    return out, ec_ov
 
 
-def evaluate_once(plan: ReadabilityPlan, pos, edges, *,
-                  n_valid_vertices=None, n_valid_edges=None,
-                  use_kernels: bool = False) -> EngineResult:
-    """One fused evaluation, eagerly (no jit cache entry).
-
-    Same program as :func:`evaluate_planned` minus the compilation: the
-    right call when the plan is fresh-per-layout (the ``backend="eager"``
-    path of :class:`repro.api.Evaluator`), where jitting would recompile
-    on every call and grow the jit cache without bound."""
-    return _evaluate(plan, pos, edges, use_kernels,
-                     n_valid_vertices, n_valid_edges)
-
-
-def _evaluate_planned(plan, pos, edges, n_valid_vertices=None,
-                      n_valid_edges=None, use_kernels=False):
-    return _evaluate(plan, pos, edges, use_kernels,
-                     n_valid_vertices, n_valid_edges)
+def _kernel_strip_stats(plan: ReadabilityPlan, axis_i: int, cap: int,
+                        with_angle: bool, segs):
+    """The ``use_kernels`` strip stage of ONE layout: flat
+    ``(n_strips, cap)`` buckets swept by the Pallas per-strip kernel (it
+    pads cap to lane multiples anyway, so tiering would buy nothing).
+    Returns ``(count, deviation_sum, dropped)``, drops of the segment
+    budget included."""
+    with jax.named_scope(f"strips.build/axis{axis_i}"):
+        buckets = gridlib.bucketize_segments(segs, plan.n_strips, cap)
+    with jax.named_scope(f"strips.sweep/axis{axis_i}/tier0"):
+        cnt, dev = fused_reversal_stats(
+            buckets, ideal=plan.ideal,
+            strip_block=min(plan.strip_block, plan.n_strips),
+            with_angle=with_angle, use_kernels=True)
+    return cnt, dev, buckets.overflow
 
 
 def evaluate_batched_body(plan: ReadabilityPlan, batch_pos, edges,
-                          n_valid_vertices=None,
-                          n_valid_edges=None) -> EngineResult:
-    """The natively batched engine program: ``(B, V, 2)`` in one pass.
+                          n_valid_vertices=None, n_valid_edges=None,
+                          use_kernels: bool = False) -> EngineResult:
+    """The engine program: ``(B, V, 2)`` layouts of one graph in one pass.
 
     No per-layout dispatch: each bucketing step groups the whole batch
     with ONE composite-key sort and materializes buckets by gathers
     (vmapped argsort/scatter is what made ``evaluate_layouts`` slower
     than a Python loop), and the occupancy-tiered reversal sweep covers
-    ``(B * n_strips_t, cap_t)`` rows per tier.  Integer metrics are
-    bit-identical to looping
-    :func:`_evaluate` over the batch members (same decompositions, same
-    pair formulas, order-independent integer sums).
+    ``(B * n_strips_t, cap_t)`` rows per tier.  A single layout is
+    ``B=1`` (:func:`evaluate_planned`, :func:`evaluate_once`).
 
-    This function is the ONE source of truth for the batched program:
-    the single-host jit (:func:`evaluate_layouts`) traces it whole, and
-    the mesh-sharded driver
+    This function is the ONE source of truth for the fused program: the
+    single-host jits trace it whole, and the mesh-sharded driver
     (:func:`repro.distributed.batched.evaluate_layouts_sharded`) traces
     it per shard on the batch-axis slice — every per-layout value is
     computed by per-layout-independent code (each bucketing sort is
-    per-row, each sweep reduction per-layout), which is what makes the
-    sharded composition bit-identical on integer metrics for free.
+    per-row, each sweep reduction per-layout), which is what makes
+    integer metrics bit-identical whatever the batch size or sharding.
+
+    ``use_kernels=True`` swaps two stages for Pallas kernels, each run
+    per layout under ``vmap``: the exact tiled pairwise occlusion count
+    (same count as the grid, paper Table 3; no capacities to overflow)
+    and the flat per-strip reversal sweep.
     """
     global _trace_count
     if isinstance(batch_pos, jax.core.Tracer):
@@ -626,26 +562,26 @@ def evaluate_batched_body(plan: ReadabilityPlan, batch_pos, edges,
     pos = jnp.asarray(batch_pos, plan.dtype)
     edges = jnp.asarray(edges, jnp.int32)
     B = pos.shape[0]
-    vertex_valid = None
-    if n_valid_vertices is not None:
-        vertex_valid = (jnp.arange(pos.shape[1], dtype=jnp.int32)
-                        < jnp.asarray(n_valid_vertices, jnp.int32))
-    edge_valid = None
-    if n_valid_edges is not None:
-        edge_valid = (jnp.arange(edges.shape[0], dtype=jnp.int32)
-                      < jnp.asarray(n_valid_edges, jnp.int32))
+    vertex_valid, edge_valid = _valid_masks(
+        pos.shape[1], edges.shape[0], n_valid_vertices, n_valid_edges)
     m = plan.metrics
     out = {}
     overflow = jnp.zeros(B, jnp.int32)
 
     if "node_occlusion" in m:
         with jax.named_scope("occlusion"):
-            cnt, ov = count_occlusions_gridded_batched(
-                pos, plan.radius, plan.grid_origin, plan.grid_nx,
-                plan.grid_ny, plan.cell_cap, valid=vertex_valid,
-                cell_block=min(plan.cell_block, plan.grid_nx * plan.grid_ny),
-                cell_size=plan.grid_cell_size)
-        overflow = overflow + ov
+            if use_kernels:
+                from repro.kernels.ops import occlusion_count_op
+                cnt = jax.vmap(lambda p: occlusion_count_op(
+                    p, plan.radius, valid=vertex_valid))(pos)
+            else:
+                cnt, ov = count_occlusions_gridded_batched(
+                    pos, plan.radius, plan.grid_origin, plan.grid_nx,
+                    plan.grid_ny, plan.cell_cap, valid=vertex_valid,
+                    cell_block=min(plan.cell_block,
+                                   plan.grid_nx * plan.grid_ny),
+                    cell_size=plan.grid_cell_size)
+                overflow = overflow + ov
         out["node_occlusion"] = cnt
     if "minimum_angle" in m:
         with jax.named_scope("min_angle"):
@@ -662,38 +598,40 @@ def evaluate_batched_body(plan: ReadabilityPlan, batch_pos, edges,
         stats = []
         for axis_i, (axis, (max_segments, cap)) in enumerate(
                 zip(plan.axes, plan.strip_plans)):
+            # strip build + bucketing happen ONCE per orientation; the one
+            # fused sweep serves both E_c and E_ca
             with jax.named_scope(f"strips.build/axis{axis_i}"):
                 segs = gridlib.build_strip_segments_batched(
                     pos, edges, plan.n_strips, max_segments, axis=axis,
                     edge_valid=edge_valid)
-            cnt, dev, drop = _tiered_strip_stats(
-                plan, axis_i, segs, B, with_angle=want_eca)
-            stats.append((cnt, dev, drop + segs.overflow))
-        with jax.named_scope("crossing.select"):
-            if len(stats) == 1:
-                (ec_count, best_dev, ec_ov) = stats[0]
-                best_count = ec_count
+            if use_kernels:
+                stats.append(jax.vmap(functools.partial(
+                    _kernel_strip_stats, plan, axis_i, cap, want_eca))(segs))
             else:
-                (c0, d0, o0), (c1, d1, o1) = stats
-                ec_count = jnp.maximum(c0, c1)
-                ec_ov = jnp.maximum(o0, o1)
-                take1 = c1 > c0
-                best_count = jnp.where(take1, c1, c0)
-                best_dev = jnp.where(take1, d1, d0)
-            if want_ec:
-                out["edge_crossing"] = ec_count
-            if want_eca:
-                out["edge_crossing_angle"] = jnp.where(
-                    best_count > 0,
-                    1.0 - best_dev / jnp.maximum(best_count, 1), 1.0)
-                out["crossing_count_for_angle"] = best_count
+                cnt, dev, drop = _tiered_strip_stats(
+                    plan, axis_i, segs, B, with_angle=want_eca)
+                stats.append((cnt, dev, drop + segs.overflow))
+        crossing, ec_ov = _select_orientation(stats, want_ec, want_eca)
+        out.update(crossing)
         overflow = overflow + ec_ov
 
     return EngineResult(overflow=overflow, **out)
 
 
-# in-repo callers predating the public name (shared per-shard body)
-_evaluate_batched = evaluate_batched_body
+def evaluate_once(plan: ReadabilityPlan, pos, edges, *,
+                  n_valid_vertices=None, n_valid_edges=None,
+                  use_kernels: bool = False) -> EngineResult:
+    """One fused evaluation of ONE layout, eagerly (no jit cache entry):
+    member 0 of :func:`evaluate_batched_body` at ``B=1``.
+
+    Same program as :func:`evaluate_planned` minus the compilation: the
+    right call when the plan is fresh-per-layout (the ``backend="eager"``
+    path of :class:`repro.api.Evaluator`), where jitting would recompile
+    on every call and grow the jit cache without bound."""
+    res = evaluate_batched_body(plan, jnp.asarray(pos, plan.dtype)[None],
+                                edges, n_valid_vertices, n_valid_edges,
+                                use_kernels)
+    return jax.tree_util.tree_map(lambda a: a[0], res)
 
 
 # ---------------------------------------------------------------------------
@@ -724,11 +662,8 @@ def _shard_occlusion(plan: ReadabilityPlan, pos, vertex_valid, shard,
     origin, size = plan.grid_origin, plan.grid_cell_size
 
     gridlib.CALL_COUNTS["cell_builds"] += 1
-    ix = jnp.clip(jnp.floor((pos[:, 0] - origin[0]) / size)
-                  .astype(jnp.int32), 0, nx - 1)
-    iy = jnp.clip(jnp.floor((pos[:, 1] - origin[1]) / size)
-                  .astype(jnp.int32), 0, ny - 1)
-    cid = iy * nx + ix                                     # (V,)
+    _, _, cid = gridlib.cell_indices(pos, plan.radius, origin, nx, ny,
+                                     cell_size=size)       # (V,)
     c0 = (shard * per_c).astype(jnp.int32)
     local = cid - c0
     own = (local >= 0) & (local < per_c)
@@ -923,8 +858,8 @@ def evaluate_graph_shard_body(plan: ReadabilityPlan, pos, edges, *,
       owns, and ONE psum of ``(sum of d_v, counted vertices)`` joins
       them.  Each ``d_v`` is bit-identical to the single-host path's;
       the final float sum differs in order only;
-    * **M_l**: O(E) replicated (the same call the single-host path
-      makes, so it is bit-identical).
+    * **M_l**: O(E) replicated (the single-layout formula, which the
+      batched form matches bitwise).
 
     Integer metrics are bit-identical to the single-host fused path under
     the same (flat-capacity) plan and invariant to the shard count: kept
@@ -935,11 +870,12 @@ def evaluate_graph_shard_body(plan: ReadabilityPlan, pos, edges, *,
 
     Named scopes in the op metadata: ``graph_shard.occlusion`` (with
     ``graph_shard.halo`` around the exchange), ``strips.build/axis{i}``
-    (the replicated build and this shard's bucketing, named as in the
-    fused path so that a change to the shared build reads the same in
-    both traces), ``graph_shard.sweep/axis{i}``, ``graph_shard.reduce``
-    (the psums), ``min_angle`` (this shard's share of M_a) and
-    ``edge_length``.
+    (the replicated build — the fused path's builder at ``B=1`` — and
+    this shard's bucketing, named as in the fused path so that a change
+    to the shared build reads the same in both traces),
+    ``graph_shard.sweep/axis{i}``, ``graph_shard.reduce`` (the psums),
+    ``crossing.select`` (the orientation vote), ``min_angle`` (this
+    shard's share of M_a) and ``edge_length``.
     """
     global _trace_count
     if isinstance(pos, jax.core.Tracer):
@@ -951,14 +887,8 @@ def evaluate_graph_shard_body(plan: ReadabilityPlan, pos, edges, *,
     edges = jnp.asarray(edges, jnp.int32)
     shard = lax.axis_index(axis_name)
     spec = plan.graph_shard
-    vertex_valid = None
-    if n_valid_vertices is not None:
-        vertex_valid = (jnp.arange(pos.shape[0], dtype=jnp.int32)
-                        < jnp.asarray(n_valid_vertices, jnp.int32))
-    edge_valid = None
-    if n_valid_edges is not None:
-        edge_valid = (jnp.arange(edges.shape[0], dtype=jnp.int32)
-                      < jnp.asarray(n_valid_edges, jnp.int32))
+    vertex_valid, edge_valid = _valid_masks(
+        pos.shape[0], edges.shape[0], n_valid_vertices, n_valid_edges)
     m = plan.metrics
     out = {}
     overflow = jnp.zeros((), jnp.int32)
@@ -993,9 +923,9 @@ def evaluate_graph_shard_body(plan: ReadabilityPlan, pos, edges, *,
         for axis_i, (axis, (max_segments, cap)) in enumerate(
                 zip(plan.axes, plan.strip_plans)):
             with jax.named_scope(f"strips.build/axis{axis_i}"):
-                segs = gridlib.build_strip_segments(
-                    pos, edges, plan.n_strips, max_segments, axis=axis,
-                    edge_valid=edge_valid)
+                segs = gridlib.build_strip_segments_batched(
+                    pos[None], edges, plan.n_strips, max_segments,
+                    axis=axis, edge_valid=edge_valid)
                 lkey = segs.strip - s0
                 # segs.valid is load-bearing beyond masking padding: the
                 # trash strip id (n_strips) can fall inside the LAST
@@ -1004,11 +934,9 @@ def evaluate_graph_shard_body(plan: ReadabilityPlan, pos, edges, *,
                 own = segs.valid & (lkey >= 0) & (lkey < per_s)
                 yl, yr, th, v, u, ok, _, drop = \
                     gridlib.gather_ragged_buckets(
-                        lkey[None], per_s,
-                        np.arange(per_s, dtype=np.int64) * cap,
-                        np.full(per_s, cap, np.int64), segs.yl[None],
-                        segs.yr[None], segs.theta[None], segs.v[None],
-                        segs.u[None], valid=own[None])
+                        lkey, per_s, np.arange(per_s, dtype=np.int64) * cap,
+                        np.full(per_s, cap, np.int64), segs.yl, segs.yr,
+                        segs.theta, segs.v, segs.u, valid=own)
             gridlib.CALL_COUNTS["reversal_sweeps"] += 1
             with jax.named_scope(f"graph_shard.sweep/axis{axis_i}"):
                 rc, rd = _reversal_rows(
@@ -1023,56 +951,32 @@ def evaluate_graph_shard_body(plan: ReadabilityPlan, pos, edges, *,
                 # segs.overflow is replicated (identical on every
                 # device): add it once, outside the psum of the
                 # per-shard drops
-                ov_ax = lax.psum(drop[0], axis_name) + segs.overflow
+                ov_ax = lax.psum(drop[0], axis_name) + segs.overflow[0]
             stats.append((cnt, dev, ov_ax))
-        if len(stats) == 1:
-            (ec_count, best_dev, ec_ov) = stats[0]
-            best_count = ec_count
-        else:
-            (c0_, d0, o0), (c1, d1, o1) = stats
-            ec_count = jnp.maximum(c0_, c1)
-            ec_ov = jnp.maximum(o0, o1)
-            take1 = c1 > c0_
-            best_count = jnp.where(take1, c1, c0_)
-            best_dev = jnp.where(take1, d1, d0)
-        if want_ec:
-            out["edge_crossing"] = ec_count
-        if want_eca:
-            out["edge_crossing_angle"] = jnp.where(
-                best_count > 0,
-                1.0 - best_dev / jnp.maximum(best_count, 1), 1.0)
-            out["crossing_count_for_angle"] = best_count
+        crossing, ec_ov = _select_orientation(stats, want_ec, want_eca)
+        out.update(crossing)
         overflow = overflow + ec_ov
 
     return EngineResult(overflow=overflow, **out)
 
 
-def _evaluate_layouts(plan, batch_pos, edges, n_valid_vertices=None,
-                      n_valid_edges=None, use_kernels=False):
-    if use_kernels:
-        # the Pallas kernels are single-layout tiles; keep the vmapped
-        # dispatch for that (TPU-targeted) route
-        return jax.vmap(
-            lambda p: _evaluate(plan, p, edges, use_kernels,
-                                n_valid_vertices, n_valid_edges))(batch_pos)
-    return evaluate_batched_body(plan, batch_pos, edges,
-                                 n_valid_vertices, n_valid_edges)
+@functools.partial(jax.jit, static_argnames=("plan", "use_kernels"))
+def evaluate_planned(plan: ReadabilityPlan, pos, edges, n_valid_vertices=None,
+                     n_valid_edges=None, use_kernels: bool = False
+                     ) -> EngineResult:
+    """All five metrics for one layout under ``plan``, fused + jitted:
+    :func:`evaluate_once` (the batched program at ``B=1``) under jit.
+
+    Returns an :class:`EngineResult` of device scalars (one transfer
+    fetches all).  ``plan`` is static: repeated calls with the same plan
+    and shapes hit the jit cache.  The optional ``n_valid_*`` scalars are
+    *traced*, so bucket-padded requests of any natural size share one
+    cache entry (see the module docstring's padding contract)."""
+    return evaluate_once(plan, pos, edges, n_valid_vertices=n_valid_vertices,
+                         n_valid_edges=n_valid_edges, use_kernels=use_kernels)
 
 
-evaluate_planned = jax.jit(_evaluate_planned,
-                           static_argnames=("plan", "use_kernels"))
-evaluate_planned.__doc__ = (
-    """All five metrics for one layout under ``plan``, fused + jitted.
-
-    ``evaluate_planned(plan, pos, edges, n_valid_vertices=None,
-    n_valid_edges=None, use_kernels=False)`` -> :class:`EngineResult` of
-    device scalars (one transfer fetches all).  ``plan`` is static:
-    repeated calls with the same plan and shapes hit the jit cache.  The
-    optional ``n_valid_*`` scalars are *traced*, so bucket-padded
-    requests of any natural size share one cache entry (see the module
-    docstring's padding contract).""")
-
-evaluate_layouts = jax.jit(_evaluate_layouts,
+evaluate_layouts = jax.jit(evaluate_batched_body,
                            static_argnames=("plan", "use_kernels"))
 evaluate_layouts.__doc__ = (
     """Batched evaluation: ``(B, V, 2)`` candidate layouts of one graph

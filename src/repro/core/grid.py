@@ -77,18 +77,6 @@ def count_dtype():
     return jnp.int64 if jax.config.jax_enable_x64 else jnp.int32
 
 
-class CellBuckets(NamedTuple):
-    """Dense capacity-padded buckets of vertices binned into grid cells."""
-
-    x: jax.Array        # (n_cells, cap) float
-    y: jax.Array        # (n_cells, cap) float
-    valid: jax.Array    # (n_cells, cap) bool
-    counts: jax.Array   # (n_cells,) int32 true occupancy (pre-capacity-clip)
-    overflow: jax.Array  # () int32: number of vertices dropped by the cap
-    nx: int             # static grid width (cells)
-    ny: int             # static grid height (cells)
-
-
 class StripSegments(NamedTuple):
     """Per-strip 'comparable' line segments (paper S3.2.2).
 
@@ -297,7 +285,8 @@ def gather_ragged_buckets(keys: jax.Array, n_buckets: int, bucket_offset,
 
 def cell_indices(pos: jax.Array, radius, origin, nx: int, ny: int,
                  cell_size=None):
-    """Cell (ix, iy) and flat id for each vertex centre.
+    """Cell (ix, iy) and flat id for each vertex centre of ``(..., 2)``
+    positions.
 
     ``cell_size`` defaults to the paper's 2r; any size >= 2r keeps the
     half-neighbourhood sweep exact (a pair closer than 2r <= size still
@@ -307,21 +296,11 @@ def cell_indices(pos: jax.Array, radius, origin, nx: int, ny: int,
     dominates the dense sweep.
     """
     size = 2.0 * radius if cell_size is None else cell_size
-    ix = jnp.clip(jnp.floor((pos[:, 0] - origin[0]) / size).astype(jnp.int32), 0, nx - 1)
-    iy = jnp.clip(jnp.floor((pos[:, 1] - origin[1]) / size).astype(jnp.int32), 0, ny - 1)
+    ix = jnp.clip(jnp.floor((pos[..., 0] - origin[0]) / size)
+                  .astype(jnp.int32), 0, nx - 1)
+    iy = jnp.clip(jnp.floor((pos[..., 1] - origin[1]) / size)
+                  .astype(jnp.int32), 0, ny - 1)
     return ix, iy, iy * nx + ix
-
-
-def build_cell_buckets(pos: jax.Array, radius, origin, nx: int, ny: int,
-                       cap: int, valid=None, cell_size=None) -> CellBuckets:
-    """Bin vertices into the occlusion grid (paper fig 1 A-1/A-2)."""
-    CALL_COUNTS["cell_builds"] += 1
-    _, _, cid = cell_indices(pos, radius, origin, nx, ny,
-                             cell_size=cell_size)
-    x, y, bvalid, counts, overflow = scatter_to_buckets(
-        cid, nx * ny, cap, pos[:, 0], pos[:, 1], valid=valid)
-    return CellBuckets(x=x, y=y, valid=bvalid, counts=counts,
-                       overflow=overflow, nx=nx, ny=ny)
 
 
 def neighbour_bucket_ids(nx: int, ny: int):
@@ -368,10 +347,60 @@ def slot_edge_ids(offsets: jax.Array, max_segments: int) -> jax.Array:
         return eid.reshape(*lead, max_segments)
 
 
-def build_strip_segments(pos: jax.Array, edges: jax.Array, n_strips: int,
-                         max_segments: int, *, axis: int = 0,
-                         domain=None, edge_valid=None) -> StripSegments:
-    """Clip edges into per-strip comparable segments.
+def strip_extent(x1, x2, edge_valid):
+    """Strip domain ``(lo, hi)``: the valid edges' extent along the strip
+    axis, reduced over the last (edge) axis, which is kept with size 1.
+
+    No valid edge leaves the extent empty (``lo = +inf``); it is pinned
+    to ``(0, 1)`` so that the (fully masked) boundary ordinates stay
+    finite — ``inf * 0`` would plant forward NaNs that the hard
+    comparisons shrug off but that poison gradients through the soft
+    path (0 cotangent x NaN value is still NaN in the backward pass).  A
+    NaN extent (an unvalidated NaN position) is left as it is: every
+    span then converts to zero strips, so such a layout yields no
+    segments rather than segments over a made-up domain."""
+    lo = jnp.min(jnp.where(edge_valid, jnp.minimum(x1, x2), jnp.inf),
+                 axis=-1, keepdims=True)
+    hi = jnp.max(jnp.where(edge_valid, jnp.maximum(x1, x2), -jnp.inf),
+                 axis=-1, keepdims=True)
+    empty = lo == jnp.inf
+    return jnp.where(empty, 0.0, lo), jnp.where(empty, 1.0, hi)
+
+
+def strip_spans(x1, x2, lo, hi, n_strips: int, ok):
+    """The strips an edge fully spans, ``s in [s_first, s_last]``, and
+    their number ``n_seg`` (0 where not ``ok``), from the edge's strip-axis
+    endpoints and the domain ``(lo, hi)`` cut into ``n_strips``."""
+    width = jnp.maximum((hi - lo) / n_strips, 1e-30)
+    xa = jnp.minimum(x1, x2)
+    xb = jnp.maximum(x1, x2)
+    s_first = jnp.clip(jnp.ceil((xa - lo) / width).astype(jnp.int32),
+                       0, n_strips - 1)
+    s_last = jnp.clip(jnp.floor((xb - lo) / width).astype(jnp.int32) - 1,
+                      -1, n_strips - 1)
+    n_seg = jnp.where(ok, jnp.maximum(0, s_last - s_first + 1), 0)
+    return s_first, s_last, n_seg
+
+
+def strip_ordinates(x1, y1, x2, y2, strip, lo, hi, n_strips: int):
+    """``(yl, yr)``: where the edge ``(x1, y1)-(x2, y2)`` (strip axis
+    first) crosses the left and right boundary lines of ``strip``."""
+    width = jnp.maximum((hi - lo) / n_strips, 1e-30)
+    dx = x2 - x1
+    slope = (y2 - y1) / jnp.where(jnp.abs(dx) < 1e-30, 1e-30, dx)
+    bl = lo + strip.astype(x1.dtype) * width
+    br = bl + width
+    return y1 + (bl - x1) * slope, y1 + (br - x1) * slope
+
+
+def build_strip_segments_batched(pos: jax.Array, edges: jax.Array,
+                                 n_strips: int, max_segments: int, *,
+                                 axis: int = 0, edge_valid=None,
+                                 safe_theta: bool = False) -> StripSegments:
+    """Clip edges into per-strip comparable segments: ``(B, V, 2)``
+    layouts of one graph -> :class:`StripSegments` with
+    ``(B, max_segments)`` fields and ``(B,)`` overflow.  A single layout
+    is ``B=1``.
 
     An edge contributes a segment to strip ``s`` iff it crosses *both* of
     the strip's boundary lines (the paper's comparability condition); its
@@ -382,78 +411,12 @@ def build_strip_segments(pos: jax.Array, edges: jax.Array, n_strips: int,
     ``axis=0``: vertical strips over x (paper default). ``axis=1``:
     horizontal strips (used by the 'both orientations' accuracy trick,
     Table 4) — implemented by swapping the roles of x and y.
-    """
-    from repro.core.geometry import segment_theta
 
-    CALL_COUNTS["strip_builds"] += 1
-
-    p = pos[edges[:, 0]]
-    q = pos[edges[:, 1]]
-    x1, y1 = p[:, axis], p[:, 1 - axis]
-    x2, y2 = q[:, axis], q[:, 1 - axis]
-    theta = segment_theta(p[:, 0], p[:, 1], q[:, 0], q[:, 1])
-    if edge_valid is None:
-        edge_valid = jnp.ones(edges.shape[0], dtype=bool)
-
-    if domain is None:
-        lo = jnp.min(jnp.where(edge_valid, jnp.minimum(x1, x2), jnp.inf))
-        hi = jnp.max(jnp.where(edge_valid, jnp.maximum(x1, x2), -jnp.inf))
-    else:
-        lo, hi = domain
-    width = jnp.maximum((hi - lo) / n_strips, 1e-30)
-
-    xa = jnp.minimum(x1, x2)
-    xb = jnp.maximum(x1, x2)
-    # strips fully spanned: s in [ceil((xa-lo)/w), floor((xb-lo)/w) - 1]
-    s_first = jnp.ceil((xa - lo) / width).astype(jnp.int32)
-    s_last = jnp.floor((xb - lo) / width).astype(jnp.int32) - 1
-    s_first = jnp.clip(s_first, 0, n_strips - 1)
-    s_last = jnp.clip(s_last, -1, n_strips - 1)
-    n_seg = jnp.where(edge_valid, jnp.maximum(0, s_last - s_first + 1), 0)
-
-    offsets = jnp.cumsum(n_seg)                      # inclusive
-    total = offsets[-1]
-    starts = offsets - n_seg                          # exclusive
-    slot = jnp.arange(max_segments, dtype=jnp.int32)
-    eid = jnp.minimum(slot_edge_ids(offsets, max_segments),
-                      edges.shape[0] - 1)
-    valid = slot < total
-    s_local = slot - starts[eid]
-    strip = s_first[eid] + s_local
-
-    ex1, ey1, ex2, ey2 = x1[eid], y1[eid], x2[eid], y2[eid]
-    # y along the edge at the two boundary lines of the strip
-    dx = ex2 - ex1
-    slope = (ey2 - ey1) / jnp.where(jnp.abs(dx) < 1e-30, 1e-30, dx)
-    bl = lo + strip.astype(pos.dtype) * width
-    br = bl + width
-    yl = ey1 + (bl - ex1) * slope
-    yr = ey1 + (br - ex1) * slope
-
-    return StripSegments(
-        strip=jnp.where(valid, strip, n_strips),
-        yl=yl, yr=yr, theta=theta[eid],
-        v=edges[eid, 0], u=edges[eid, 1],
-        valid=valid,
-        overflow=jnp.maximum(total - max_segments, 0).astype(jnp.int32),
-        eid=eid,
-    )
-
-
-def build_strip_segments_batched(pos: jax.Array, edges: jax.Array,
-                                 n_strips: int, max_segments: int, *,
-                                 axis: int = 0, edge_valid=None,
-                                 safe_theta: bool = False) -> StripSegments:
-    """Batched :func:`build_strip_segments`: ``(B, V, 2)`` layouts of one
-    graph -> :class:`StripSegments` with ``(B, max_segments)`` fields and
-    ``(B,)`` overflow.
-
-    Mirrors the single-layout function formula-for-formula (same
-    elementwise op sequence, so boundary ordinates round identically and
-    integer crossing counts stay bit-compatible with the looped path;
-    both find each slot's parent edge with :func:`slot_edge_ids`); only
-    the indexing machinery grows a leading batch axis.  Strip ids
-    stay *per-layout* (in ``[0, n_strips]``, ``n_strips`` = trash) —
+    The span and ordinate arithmetic is :func:`strip_extent`,
+    :func:`strip_spans` and :func:`strip_ordinates`, which the
+    incremental path calls too; each slot's parent edge comes from
+    :func:`slot_edge_ids`.  Strip ids stay *per-layout* (in
+    ``[0, n_strips]``, ``n_strips`` = trash) —
     :func:`gather_ragged_buckets` consumes the ``(B, max_segments)`` key
     rows directly, one sorted row per layout.
 
@@ -463,13 +426,12 @@ def build_strip_segments_batched(pos: jax.Array, edges: jax.Array,
     ``arctan2(0, 0)``'s NaN partials — the differentiable soft path
     (:mod:`repro.core.soft`) needs this because one NaN partial poisons
     the whole backward pass even under a zero cotangent.  The exact
-    paths keep the default (same ops as the single-layout builder).
+    paths keep the default.
     """
     from repro.core.geometry import segment_theta, segment_theta_safe
 
     CALL_COUNTS["strip_builds"] += 1
 
-    B = pos.shape[0]
     p = pos[:, edges[:, 0]]                          # (B, E, 2)
     q = pos[:, edges[:, 1]]
     x1, y1 = p[..., axis], p[..., 1 - axis]
@@ -480,27 +442,8 @@ def build_strip_segments_batched(pos: jax.Array, edges: jax.Array,
         edge_valid = jnp.ones(edges.shape[0], dtype=bool)
     ev = jnp.broadcast_to(edge_valid, x1.shape)      # one mask, all layouts
 
-    lo = jnp.min(jnp.where(ev, jnp.minimum(x1, x2), jnp.inf),
-                 axis=1, keepdims=True)
-    hi = jnp.max(jnp.where(ev, jnp.maximum(x1, x2), -jnp.inf),
-                 axis=1, keepdims=True)
-    # zero valid edges leaves the extent empty (lo = +inf): pin it to a
-    # finite dummy so the (fully masked) boundary ordinates below stay
-    # finite — ``inf * 0`` would plant forward NaNs that the hard
-    # comparisons shrug off but that poison gradients through the soft
-    # path (0 cotangent x NaN value is still NaN in the backward pass)
-    some = jnp.isfinite(lo)
-    lo = jnp.where(some, lo, 0.0)
-    hi = jnp.where(some, hi, 1.0)
-    width = jnp.maximum((hi - lo) / n_strips, 1e-30)
-
-    xa = jnp.minimum(x1, x2)
-    xb = jnp.maximum(x1, x2)
-    s_first = jnp.ceil((xa - lo) / width).astype(jnp.int32)
-    s_last = jnp.floor((xb - lo) / width).astype(jnp.int32) - 1
-    s_first = jnp.clip(s_first, 0, n_strips - 1)
-    s_last = jnp.clip(s_last, -1, n_strips - 1)
-    n_seg = jnp.where(ev, jnp.maximum(0, s_last - s_first + 1), 0)
+    lo, hi = strip_extent(x1, x2, ev)                # (B, 1) each
+    s_first, _, n_seg = strip_spans(x1, x2, lo, hi, n_strips, ev)
 
     offsets = jnp.cumsum(n_seg, axis=1)              # (B, E) inclusive
     total = offsets[:, -1:]                          # (B, 1)
@@ -509,17 +452,10 @@ def build_strip_segments_batched(pos: jax.Array, edges: jax.Array,
     eid = jnp.minimum(slot_edge_ids(offsets, max_segments),
                       edges.shape[0] - 1)
     valid = slot[None, :] < total
-    s_local = slot[None, :] - jnp.take_along_axis(starts, eid, axis=1)
-    strip = jnp.take_along_axis(s_first, eid, axis=1) + s_local
-
     ga = lambda a: jnp.take_along_axis(a, eid, axis=1)
-    ex1, ey1, ex2, ey2 = ga(x1), ga(y1), ga(x2), ga(y2)
-    dx = ex2 - ex1
-    slope = (ey2 - ey1) / jnp.where(jnp.abs(dx) < 1e-30, 1e-30, dx)
-    bl = lo + strip.astype(pos.dtype) * width
-    br = bl + width
-    yl = ey1 + (bl - ex1) * slope
-    yr = ey1 + (br - ex1) * slope
+    strip = ga(s_first) + (slot[None, :] - ga(starts))
+    yl, yr = strip_ordinates(ga(x1), ga(y1), ga(x2), ga(y2), strip, lo, hi,
+                             n_strips)
 
     return StripSegments(
         strip=jnp.where(valid, strip, n_strips),

@@ -63,7 +63,9 @@ import numpy as np
 from repro import tracing
 from repro.core import grid as gridlib
 from repro.core.edge_length import edge_length_variation
-from repro.core.engine import ReadabilityPlan, ReadabilityScores, _reversal_rows
+from repro.core.engine import (ReadabilityPlan, ReadabilityScores,
+                               _reversal_rows, _select_orientation,
+                               _valid_masks)
 from repro.core.geometry import TWO_PI, directed_angle, segment_theta
 
 
@@ -87,8 +89,8 @@ class ResidentState(NamedTuple):
 
     Slot *values* (coordinates, boundary ordinates, thetas) are never
     stored — only membership (ids + validity) and the reduced partials.
-    Values are re-derived from ``pos`` at use time by the exact formula
-    mirrors below, so a delta can never read a stale coordinate.
+    Values are re-derived from ``pos`` at use time by the full path's
+    formulas, so a delta can never read a stale coordinate.
     Metric-absent fields are ``None`` (stable per plan, so the jit
     treedef is stable too).
     """
@@ -168,68 +170,48 @@ def owner_cells(dirty, nx: int, ny: int) -> np.ndarray:
 
 
 # ---------------------------------------------------------------------------
-# exact formula mirrors (same elementwise op sequences as the full path)
+# the full path's formulas, on the resident tables
 # ---------------------------------------------------------------------------
 
-def _cell_ids(x, y, plan: ReadabilityPlan):
-    """Flat cell id per point — mirrors :func:`repro.core.grid.cell_indices`."""
-    size = plan.grid_cell_size
-    ox, oy = plan.grid_origin
-    ix = jnp.clip(jnp.floor((x - ox) / size).astype(jnp.int32),
-                  0, plan.grid_nx - 1)
-    iy = jnp.clip(jnp.floor((y - oy) / size).astype(jnp.int32),
-                  0, plan.grid_ny - 1)
-    return iy * plan.grid_nx + ix
+def _cell_ids(xy, plan: ReadabilityPlan):
+    """Flat cell id per ``(..., 2)`` point on the plan's occlusion grid."""
+    return gridlib.cell_indices(xy, plan.radius, plan.grid_origin,
+                                plan.grid_nx, plan.grid_ny,
+                                cell_size=plan.grid_cell_size)[2]
 
 
 def _strip_domain(pos, edges, edge_valid, axis: int):
-    """(lo, hi) exactly as ``build_strip_segments`` derives them."""
-    x1 = pos[edges[:, 0], axis]
-    x2 = pos[edges[:, 1], axis]
-    lo = jnp.min(jnp.where(edge_valid, jnp.minimum(x1, x2), jnp.inf))
-    hi = jnp.max(jnp.where(edge_valid, jnp.maximum(x1, x2), -jnp.inf))
-    return lo, hi
+    """(lo, hi) as the strip builder derives them
+    (:func:`repro.core.grid.strip_extent`)."""
+    lo, hi = gridlib.strip_extent(pos[edges[:, 0], axis],
+                                  pos[edges[:, 1], axis], edge_valid)
+    return lo[0], hi[0]
 
 
 def _strip_spans(pos, edges, eids, ok, lo, hi, n_strips: int, axis: int):
-    """Per-edge strip span ``(s_first, s_last, n_seg)`` — mirror of the
-    span arithmetic in ``build_strip_segments`` (same casts/clips)."""
+    """Per-edge strip span ``(s_first, s_last, n_seg)`` of edges ``eids``
+    (:func:`repro.core.grid.strip_spans`, as the strip builder)."""
     e = jnp.clip(eids, 0, edges.shape[0] - 1)
-    x1 = pos[edges[e, 0], axis]
-    x2 = pos[edges[e, 1], axis]
-    width = jnp.maximum((hi - lo) / n_strips, 1e-30)
-    xa = jnp.minimum(x1, x2)
-    xb = jnp.maximum(x1, x2)
-    s_first = jnp.clip(jnp.ceil((xa - lo) / width).astype(jnp.int32),
-                       0, n_strips - 1)
-    s_last = jnp.clip(jnp.floor((xb - lo) / width).astype(jnp.int32) - 1,
-                      -1, n_strips - 1)
-    n_seg = jnp.where(ok, jnp.maximum(0, s_last - s_first + 1), 0)
-    return s_first, s_last, n_seg
+    return gridlib.strip_spans(pos[edges[e, 0], axis],
+                               pos[edges[e, 1], axis], lo, hi, n_strips, ok)
 
 
 def _strip_values(pos, edges, eid, strip, lo, hi, n_strips: int, axis: int):
-    """Slot values ``(yl, yr, theta, v, u)`` for (edge, strip) pairs —
-    mirror of the ordinate arithmetic in ``build_strip_segments``."""
+    """Slot values ``(yl, yr, theta, v, u)`` for (edge, strip) pairs
+    (:func:`repro.core.grid.strip_ordinates`, as the strip builder)."""
     e = jnp.clip(eid, 0, edges.shape[0] - 1)
     p = pos[edges[e, 0]]
     q = pos[edges[e, 1]]
     theta = segment_theta(p[:, 0], p[:, 1], q[:, 0], q[:, 1])
-    ex1, ey1 = p[:, axis], p[:, 1 - axis]
-    ex2, ey2 = q[:, axis], q[:, 1 - axis]
-    width = jnp.maximum((hi - lo) / n_strips, 1e-30)
-    dx = ex2 - ex1
-    slope = (ey2 - ey1) / jnp.where(jnp.abs(dx) < 1e-30, 1e-30, dx)
-    bl = lo + strip.astype(pos.dtype) * width
-    br = bl + width
-    yl = ey1 + (bl - ex1) * slope
-    yr = ey1 + (br - ex1) * slope
+    yl, yr = gridlib.strip_ordinates(p[:, axis], p[:, 1 - axis], q[:, axis],
+                                     q[:, 1 - axis], strip, lo, hi, n_strips)
     return yl, yr, theta, edges[e, 0], edges[e, 1]
 
 
 def _occ_rows(row_ids, vid_tab, val_tab, px, py, nbr_idx, nbr_ok, thresh):
     """Per-cell occlusion partial for the given rows — mirror of the
-    block formula in :func:`repro.core.occlusion.count_occlusions_gridded`
+    block formula in
+    :func:`repro.core.occlusion.count_occlusions_gridded_batched`
     (same-cell triangle + 4-neighbour cross pairs), reduced per row."""
     n_cells = vid_tab.shape[0]
     ok = row_ids < n_cells
@@ -321,8 +303,7 @@ def _prime_fn(plan: ReadabilityPlan, pos, edges, n_v, n_e, inc_nbr, inc_deg):
     pos = jnp.asarray(pos, plan.dtype)
     edges = jnp.asarray(edges, jnp.int32)
     vb, eb = pos.shape[0], edges.shape[0]
-    vertex_valid = jnp.arange(vb, dtype=jnp.int32) < n_v
-    edge_valid = jnp.arange(eb, dtype=jnp.int32) < n_e
+    vertex_valid, edge_valid = _valid_masks(vb, eb, n_v, n_e)
     m = plan.metrics
     overflow = jnp.zeros((), jnp.int32)
     px = jnp.concatenate([pos[:, 0], jnp.zeros(1, pos.dtype)])
@@ -332,7 +313,7 @@ def _prime_fn(plan: ReadabilityPlan, pos, edges, n_v, n_e, inc_nbr, inc_deg):
     vert_cell = jnp.zeros(vb, jnp.int32)
     if "node_occlusion" in m:
         n_cells = plan.grid_nx * plan.grid_ny
-        vert_cell = _cell_ids(pos[:, 0], pos[:, 1], plan)
+        vert_cell = _cell_ids(pos, plan)
         vid, bvalid, _, ov = gridlib.scatter_to_buckets(
             vert_cell, n_cells, plan.cell_cap,
             jnp.arange(vb, dtype=jnp.int32), valid=vertex_valid)
@@ -444,7 +425,7 @@ def _probe_fn(plan: ReadabilityPlan, state: ResidentState, edges, n_e,
     pos2 = pos.at[moved].set(jnp.asarray(new_xy, pos.dtype), mode="drop")
     new_xyc = jnp.asarray(new_xy, pos.dtype)
     with jax.named_scope("occlusion"):
-        new_cid = _cell_ids(new_xyc[:, 0], new_xyc[:, 1], plan) \
+        new_cid = _cell_ids(new_xyc, plan) \
             if "node_occlusion" in plan.metrics else jnp.zeros(
                 moved.shape, jnp.int32)
     edge_valid = jnp.arange(eb, dtype=jnp.int32) < n_e
@@ -517,7 +498,7 @@ def _delta_fn(plan: ReadabilityPlan, state: ResidentState, edges, n_e,
             # movers: their new cell, located in the sorted dirty-cell list;
             # a miss means the host dirty set was wrong -> count it lost and
             # let the session fall back rather than under-count
-            cid2 = _cell_ids(new_xyc[:, 0], new_xyc[:, 1], plan)
+            cid2 = _cell_ids(new_xyc, plan)
             lk = jnp.searchsorted(dc, cid2).astype(jnp.int32)
             found = (lk < dc_cap) & (dc[jnp.minimum(lk, dc_cap - 1)] == cid2)
             lost_cells = jnp.sum(
@@ -609,25 +590,8 @@ def _delta_fn(plan: ReadabilityPlan, state: ResidentState, edges, n_e,
             new_strips.append(ResidentStrip(eid=eid2, valid=val2,
                                             cnt=cnt2, dev=dev2,
                                             lo=st.lo, hi=st.hi))
-        # best-orientation vote, exactly as the fused engine
-        with jax.named_scope("crossing.select"):
-            if len(stats) == 1:
-                (ec_count, best_dev, ec_ov) = stats[0]
-                best_count = ec_count
-            else:
-                (c0, d0, o0), (c1, d1, o1) = stats
-                ec_count = jnp.maximum(c0, c1)
-                ec_ov = jnp.maximum(o0, o1)
-                take1 = c1 > c0
-                best_count = jnp.where(take1, c1, c0)
-                best_dev = jnp.where(take1, d1, d0)
-            if want_ec:
-                out["edge_crossing"] = ec_count
-            if want_eca:
-                out["edge_crossing_angle"] = jnp.where(
-                    best_count > 0,
-                    1.0 - best_dev / jnp.maximum(best_count, 1), 1.0)
-                out["crossing_count_for_angle"] = best_count
+        crossing, ec_ov = _select_orientation(stats, want_ec, want_eca)
+        out.update(crossing)
         overflow = overflow + ec_ov
 
     # -- min angle: re-derive moved vertices + their neighbours -------------

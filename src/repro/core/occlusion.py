@@ -55,71 +55,6 @@ def count_occlusions_exact(pos: jax.Array, radius, *, block: int = 1024,
     return jnp.sum(lax.map(row_block, starts))
 
 
-def count_occlusions_gridded(pos: jax.Array, radius, origin, nx: int, ny: int,
-                             cap: int, *, valid=None, cell_block: int = 512,
-                             cell_size=None) -> jax.Array:
-    """Enhanced N_c on a pre-planned grid (jit-friendly; static nx/ny/cap).
-
-    Exact: the cell size (>= 2r, default 2r) bounds the interaction
-    radius, so every occluding pair lands in the same cell or in a
-    half-neighbourhood pair.
-    """
-    buckets = gridlib.build_cell_buckets(pos, radius, origin, nx, ny, cap,
-                                         valid=valid, cell_size=cell_size)
-    nbr = gridlib.neighbour_bucket_ids(nx, ny)            # (C, 4)
-    n_cells = nx * ny
-    thresh = jnp.asarray((2.0 * radius) ** 2, pos.dtype)
-    # Gathering with id -1 -> use clipped index but kill validity.
-    nbr_ok = nbr >= 0
-    nbr_idx = jnp.maximum(nbr, 0)
-
-    n_blocks = -(-n_cells // cell_block)
-    pad_cells = n_blocks * cell_block
-
-    def pad_cells_arr(a, fill):
-        extra = pad_cells - n_cells
-        if extra == 0:
-            return a
-        return jnp.concatenate(
-            [a, jnp.full((extra,) + a.shape[1:], fill, a.dtype)])
-
-    x = pad_cells_arr(buckets.x, 0.0)
-    y = pad_cells_arr(buckets.y, 0.0)
-    bval = pad_cells_arr(buckets.valid, False)
-    nidx = pad_cells_arr(nbr_idx, 0)
-    nok = pad_cells_arr(nbr_ok, False)
-
-    def block_fn(b0):
-        sl = lambda a: lax.dynamic_slice_in_dim(a, b0, cell_block, axis=0)
-        bx, by, bv = sl(x), sl(y), sl(bval)
-        ni, no = sl(nidx), sl(nok)
-        # same-cell pairs (i < j)
-        cap_ = bx.shape[-1]
-        tri = jnp.arange(cap_)[:, None] < jnp.arange(cap_)[None, :]
-        d2 = ((bx[:, :, None] - bx[:, None, :]) ** 2
-              + (by[:, :, None] - by[:, None, :]) ** 2)
-        smask = bv[:, :, None] & bv[:, None, :] & tri[None]
-        same = jnp.sum(jnp.where(smask & (d2 < thresh), 1, 0),
-                       dtype=gridlib.count_dtype())
-        # half-neighbourhood pairs: gather the 4 neighbour buckets
-        cx = x[ni].reshape(cell_block, -1)                # (B, 4*cap)
-        cy = y[ni].reshape(cell_block, -1)
-        cv = (bval[ni] & no[:, :, None]).reshape(cell_block, -1)
-        cross = _cross_count(bx, by, bv, cx, cy, cv, thresh)
-        return same + cross
-
-    starts = jnp.arange(0, pad_cells, cell_block, dtype=jnp.int32)
-    return jnp.sum(lax.map(block_fn, starts)), buckets.overflow
-
-
-def _cross_count(bx, by, bv, cx, cy, cv, thresh):
-    d2 = ((bx[:, :, None] - cx[:, None, :]) ** 2
-          + (by[:, :, None] - cy[:, None, :]) ** 2)
-    mask = bv[:, :, None] & cv[:, None, :]
-    return jnp.sum(jnp.where(mask & (d2 < thresh), 1, 0),
-                   dtype=gridlib.count_dtype())
-
-
 def count_occlusions_gridded_batched(pos: jax.Array, radius, origin, nx: int,
                                      ny: int, cap: int, *, valid=None,
                                      cell_block: int = 512, cell_size=None):
@@ -130,10 +65,10 @@ def count_occlusions_gridded_batched(pos: jax.Array, radius, origin, nx: int,
     (:func:`~repro.core.grid.gather_ragged_buckets` with uniform caps; no
     scatter, no vmap — vmapped argsort/scatter over the single-layout
     counter is the exact per-call overhead that made batching slower
-    than a Python loop), then swept with per-row partial sums.  Counts
-    are bit-identical to the single-layout
-    :func:`count_occlusions_gridded` under the same grid (same cell
-    assignment, same pair formula; integer sums are order-independent).
+    than a Python loop), then swept with per-row partial sums.  A single
+    layout is ``B=1``.  Exact: the cell size (>= 2r, default 2r) bounds
+    the interaction radius, so every occluding pair lands in the same
+    cell or in a half-neighbourhood pair.
 
     ``valid`` may be ``(V,)`` (one mask for every layout — the serving
     bucket-padding case) or ``(B, V)``.
@@ -143,12 +78,8 @@ def count_occlusions_gridded_batched(pos: jax.Array, radius, origin, nx: int,
     B, V = pos.shape[0], pos.shape[1]
     n_cells = nx * ny
     gridlib.CALL_COUNTS["cell_builds"] += 1
-    size = 2.0 * radius if cell_size is None else cell_size
-    ix = jnp.clip(jnp.floor((pos[..., 0] - origin[0]) / size)
-                  .astype(jnp.int32), 0, nx - 1)
-    iy = jnp.clip(jnp.floor((pos[..., 1] - origin[1]) / size)
-                  .astype(jnp.int32), 0, ny - 1)
-    cid = iy * nx + ix                                     # (B, V)
+    _, _, cid = gridlib.cell_indices(pos, radius, origin, nx, ny,
+                                     cell_size=cell_size)  # (B, V)
     vmask = None
     if valid is not None:
         vmask = jnp.broadcast_to(jnp.asarray(valid), (B, V))
@@ -213,9 +144,9 @@ def count_occlusions_gridded_batched(pos: jax.Array, radius, origin, nx: int,
 
 def count_occlusions_enhanced(pos, radius, *, valid=None, cell_block: int = 512):
     """Host-facing enhanced N_c: plans the grid from the data, then runs the
-    gridded counter. Returns (count, overflow)."""
+    gridded counter at B=1. Returns (count, overflow)."""
     origin, nx, ny, cap, size = gridlib.plan_occlusion_grid(pos, radius)
-    count, overflow = count_occlusions_gridded(
-        jnp.asarray(pos), radius, origin, nx, ny, cap, valid=valid,
+    count, overflow = count_occlusions_gridded_batched(
+        jnp.asarray(pos)[None], radius, origin, nx, ny, cap, valid=valid,
         cell_block=min(cell_block, nx * ny), cell_size=size)
-    return count, overflow
+    return count[0], overflow[0]

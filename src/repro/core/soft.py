@@ -121,11 +121,8 @@ def _soft_occlusion(plan, pos, vertex_valid, tau):
     n_cells = nx * ny
     origin, size = plan.grid_origin, plan.grid_cell_size
     gridlib.CALL_COUNTS["cell_builds"] += 1
-    ix = jnp.clip(jnp.floor((pos[..., 0] - origin[0]) / size)
-                  .astype(jnp.int32), 0, nx - 1)
-    iy = jnp.clip(jnp.floor((pos[..., 1] - origin[1]) / size)
-                  .astype(jnp.int32), 0, ny - 1)
-    cid = iy * nx + ix
+    _, _, cid = gridlib.cell_indices(pos, plan.radius, origin, nx, ny,
+                                     cell_size=size)
     vmask = None
     if vertex_valid is not None:
         vmask = jnp.broadcast_to(vertex_valid, (B, V))
@@ -342,14 +339,8 @@ def soft_scores(plan, batch_pos, edges, temperature, *,
     edges = jnp.asarray(edges, jnp.int32)
     B = pos.shape[0]
     tau = jnp.asarray(temperature, plan.dtype)
-    vertex_valid = None
-    if n_valid_vertices is not None:
-        vertex_valid = (jnp.arange(pos.shape[1], dtype=jnp.int32)
-                        < jnp.asarray(n_valid_vertices, jnp.int32))
-    edge_valid = None
-    if n_valid_edges is not None:
-        edge_valid = (jnp.arange(edges.shape[0], dtype=jnp.int32)
-                      < jnp.asarray(n_valid_edges, jnp.int32))
+    vertex_valid, edge_valid = engine._valid_masks(
+        pos.shape[1], edges.shape[0], n_valid_vertices, n_valid_edges)
     m = plan.metrics
     out = {}
     overflow = jnp.zeros(B, jnp.int32)
@@ -380,30 +371,16 @@ def soft_scores(plan, batch_pos, edges, temperature, *,
             cnt, dev, drop = _soft_tiered_strip_stats(
                 plan, axis_i, segs, B, tau=tau_rev, with_angle=want_eca)
             stats.append((cnt, dev, drop + segs.overflow))
-        if len(stats) == 1:
-            (ec_count, best_dev, ec_ov) = stats[0]
-            best_count = ec_count
-        else:
-            (c0, d0, o0), (c1, d1, o1) = stats
-            ec_count = jnp.maximum(c0, c1)
-            ec_ov = jnp.maximum(o0, o1)
-            # same best-orientation vote as the exact body, on the soft
-            # counts (converges to the exact vote as tau -> 0 away from
-            # count ties; the selected branch carries the gradient)
-            take1 = c1 > c0
-            best_count = jnp.where(take1, c1, c0)
-            best_dev = jnp.where(take1, d1, d0)
-        if want_ec:
-            out["edge_crossing"] = ec_count
-        if want_eca:
-            # smooth form of the exact "1 - dev/max(count, 1) if count
-            # else 1": dev <= count (per-pair deviation is in [0, 1] for
-            # any ideal <= pi/2... actually bounded by max(1, pi/2/ideal
-            # - 1)), and both vanish together as the soft count -> 0, so
-            # the unconditional expression has the same limits without a
-            # non-differentiable branch on the count
-            out["edge_crossing_angle"] = (
-                1.0 - best_dev / jnp.maximum(best_count, 1.0))
+        # the exact body's best-orientation vote, on the soft counts
+        # (converges to the exact vote as tau -> 0 away from count ties;
+        # the selected branch carries the gradient).  Its E_ca branch on
+        # ``count > 0`` is smooth here: a sigmoid-weighted count is 0
+        # only where every pair is masked out, and then the deviation sum
+        # and both their gradients are 0 as well
+        crossing, ec_ov = engine._select_orientation(stats, want_ec,
+                                                     want_eca)
+        crossing.pop("crossing_count_for_angle", None)
+        out.update(crossing)
         overflow = overflow + ec_ov
 
     return SoftScores(overflow=overflow, **out)

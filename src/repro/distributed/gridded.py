@@ -169,9 +169,12 @@ def evaluate_sharded(mesh: Mesh, pos, edges, *, config=None, plan=None):
     if want_ec or want_eca:
         stats = []
         for axis, (max_segments, cap) in zip(plan.axes, plan.strip_plans):
-            segs = gridlib.build_strip_segments(
-                pos, edges, plan.n_strips, max_segments, axis=axis)
-            buckets = gridlib.bucketize_segments(segs, plan.n_strips, cap)
+            segs = gridlib.build_strip_segments_batched(
+                jnp.asarray(pos)[None], edges, plan.n_strips, max_segments,
+                axis=axis)
+            buckets = gridlib.bucketize_segments(
+                jax.tree_util.tree_map(lambda a: a[0], segs), plan.n_strips,
+                cap)
             res = sharded_reversal_stats(
                 mesh, buckets,
                 ideal_angle=plan.ideal if want_eca else None)

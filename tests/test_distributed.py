@@ -55,8 +55,10 @@ assert got_cross == want_cross, ("sharded cross", got_cross, want_cross)
 from repro.core import grid as gridlib
 from repro.core.crossing import bucket_reversal_stats
 from repro.distributed.gridded import sharded_reversal_stats
-segs = gridlib.build_strip_segments(pos, edges, 64, 16384)
-buckets = gridlib.bucketize_segments(segs, 64, cap=128)
+segs = gridlib.build_strip_segments_batched(jnp.asarray(pos)[None],
+                                           jnp.asarray(edges), 64, 16384)
+buckets = gridlib.bucketize_segments(
+    jax.tree_util.tree_map(lambda a: a[0], segs), 64, cap=128)
 (want_enh,) = bucket_reversal_stats(buckets)
 (got_enh,) = sharded_reversal_stats(mesh, buckets)
 assert int(got_enh) == int(want_enh), (int(got_enh), int(want_enh))

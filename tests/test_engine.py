@@ -19,10 +19,10 @@ from repro.core.crossing_angle import (DEFAULT_IDEAL,
                                        crossing_angle_enhanced,
                                        crossing_angle_strips)
 from repro.core.edge_length import edge_length_variation
-from repro.core.min_angle import minimum_angle
+from repro.core.min_angle import minimum_angle, minimum_angle_batched
 from repro.core.occlusion import (count_occlusions_enhanced,
                                   count_occlusions_exact,
-                                  count_occlusions_gridded)
+                                  count_occlusions_gridded_batched)
 
 N_STRIPS = 64
 RADIUS = 2.0
@@ -74,15 +74,18 @@ def unfused_reference(pos, edges, orientation="both"):
     the bit-identity assertions compare XLA-compiled against XLA-compiled
     — eager dispatch rounds a few strip-boundary ordinates differently
     (no fused multiply-add) and can flip exact ties on degenerate
-    layouts.
+    layouts.  M_a is the block the engine runs, the batched form at B=1:
+    the single-layout form, compiled on its own, fuses its per-vertex
+    arithmetic differently and can round a few ``d_v`` apart.
     """
     origin, nx, ny, cap, size = gridlib.plan_occlusion_grid(pos, RADIUS)
-    occ, occ_ov = jax.jit(count_occlusions_gridded,
+    occ, occ_ov = jax.jit(count_occlusions_gridded_batched,
                           static_argnums=(1, 2, 3, 4, 5),
                           static_argnames=("cell_block", "cell_size"))(
-        pos, RADIUS, origin, nx, ny, cap, cell_block=min(512, nx * ny),
+        pos[None], RADIUS, origin, nx, ny, cap, cell_block=min(512, nx * ny),
         cell_size=size)
-    m_a, _ = jax.jit(minimum_angle)(pos, edges)
+    occ, occ_ov = occ[0], occ_ov[0]
+    m_a = jax.jit(minimum_angle_batched)(pos[None], edges)[0][0]
     m_l = jax.jit(edge_length_variation)(pos, edges)
     axes = {"vertical": (0,), "both": (0, 1)}[orientation]
     cross, angle = [], []
@@ -167,24 +170,38 @@ def test_evaluate_layout_wrapper_matches_engine(graph):
     assert again == rep
 
 
-def test_batched_matches_looped(graph):
+@pytest.mark.parametrize("B,use_kernels", [(4, False), (1, False),
+                                           (1, True)],
+                         ids=["B4", "B1", "B1-kernels"])
+def test_batched_matches_looped(graph, B, use_kernels):
+    """A single layout is member 0 of the batched program at B=1: at B=1
+    every field is bitwise the batch's, E_ca included, on the jnp and
+    the Pallas (interpret mode off-TPU) routes."""
     pos, edges = graph
     rng = np.random.default_rng(3)
     batch = jnp.asarray(np.stack(
         [np.asarray(pos) + rng.normal(0, 1.0, size=pos.shape)
-         for _ in range(4)]).astype(np.float32))
+         for _ in range(B)]).astype(np.float32))
     plan = plan_readability(batch, edges, radius=RADIUS, n_strips=N_STRIPS)
-    got = evaluate_layouts(plan, batch, edges)
+    got = evaluate_layouts(plan, batch, edges, use_kernels=use_kernels)
     for i in range(batch.shape[0]):
-        want = evaluate_planned(plan, batch[i], edges)
+        want = evaluate_planned(plan, batch[i], edges,
+                                use_kernels=use_kernels)
         assert int(got.node_occlusion[i]) == int(want.node_occlusion)
         assert int(got.edge_crossing[i]) == int(want.edge_crossing)
-        # the natively batched sweep blocks (B * n_strips_t) rows where
-        # the B=1 path blocks n_strips_t — same per-pair terms, float
-        # reduction shape differs; integer metrics are exact above
-        np.testing.assert_allclose(float(got.edge_crossing_angle[i]),
-                                   float(want.edge_crossing_angle),
-                                   rtol=1e-6)
+        assert int(got.crossing_count_for_angle[i]) == \
+            int(want.crossing_count_for_angle)
+        if B == 1:
+            assert float(got.edge_crossing_angle[i]) == \
+                float(want.edge_crossing_angle)
+        else:
+            # the natively batched sweep blocks (B * n_strips_t) rows
+            # where the B=1 program blocks n_strips_t — same per-pair
+            # terms, float reduction shape differs; integer metrics are
+            # exact above
+            np.testing.assert_allclose(float(got.edge_crossing_angle[i]),
+                                       float(want.edge_crossing_angle),
+                                       rtol=1e-6)
         assert float(got.minimum_angle[i]) == float(want.minimum_angle)
         assert float(got.edge_length_variation[i]) == \
             float(want.edge_length_variation)
@@ -326,8 +343,9 @@ def test_shared_formula_everywhere():
     block: same count, same normalized deviation sum."""
     pos, edges = make_layout("cluster")
     from repro.core.crossing import bucket_reversal_stats
-    segs = gridlib.build_strip_segments(pos, edges, 32, 16384)
-    buckets = gridlib.bucketize_segments(segs, 32, cap=256)
+    segs = gridlib.build_strip_segments_batched(pos[None], edges, 32, 16384)
+    buckets = gridlib.bucketize_segments(
+        jax.tree_util.tree_map(lambda a: a[0], segs), 32, cap=256)
     cnt_a, dev_a = bucket_reversal_stats(buckets, ideal_angle=DEFAULT_IDEAL)
     cnt_b, dev_b = engine.fused_reversal_stats(buckets, ideal=DEFAULT_IDEAL)
     assert int(cnt_a) == int(cnt_b)

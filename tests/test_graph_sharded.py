@@ -282,6 +282,7 @@ def test_pairwise_dispatch_failure_is_typed(monkeypatch):
 
 def test_gridded_dispatch_failure_is_typed(monkeypatch):
     import jax
+    import jax.numpy as jnp
     from repro.api import BackendUnavailableError
     from repro.core import engine, grid
     from repro.distributed import gridded
@@ -290,9 +291,11 @@ def test_gridded_dispatch_failure_is_typed(monkeypatch):
     plan = engine.plan_readability(pos, edges, radius=1.0, n_strips=16,
                                    tier_strips=False)
     max_segments, cap = plan.strip_plans[0]
-    segs = grid.build_strip_segments(pos, edges, plan.n_strips,
-                                     max_segments, axis=plan.axes[0])
-    buckets = grid.bucketize_segments(segs, plan.n_strips, cap)
+    segs = grid.build_strip_segments_batched(
+        jnp.asarray(pos)[None], jnp.asarray(edges), plan.n_strips,
+        max_segments, axis=plan.axes[0])
+    buckets = grid.bucketize_segments(
+        jax.tree_util.tree_map(lambda a: a[0], segs), plan.n_strips, cap)
 
     def bad_jit(fn, **kw):
         def run(*a, **k):

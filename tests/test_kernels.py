@@ -1,6 +1,7 @@
 """Per-kernel validation: Pallas (interpret mode) vs pure-jnp oracles,
 swept over shapes and dtypes, plus hypothesis property tests."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -61,9 +62,11 @@ def test_crossing_angle_kernel(n_e):
 
 def test_strip_reversal_kernel_vs_ref():
     pos, edges = make_graph(3, 120, 400)
-    segs = gridlib.build_strip_segments(pos, edges, n_strips=32,
-                                        max_segments=8192)
-    buckets = gridlib.bucketize_segments(segs, 32, cap=256)
+    segs = gridlib.build_strip_segments_batched(
+        jnp.asarray(pos)[None], jnp.asarray(edges), n_strips=32,
+        max_segments=8192)
+    buckets = gridlib.bucketize_segments(
+        jax.tree_util.tree_map(lambda a: a[0], segs), 32, cap=256)
     count, dev = strip_reversal_op(buckets, ideal=float(DEFAULT_IDEAL),
                                    with_angle=True)
     want = 0

@@ -1,11 +1,11 @@
-"""The strip builders' slot -> parent-edge map.
+"""The strip builder's slot -> parent-edge map.
 
 :func:`repro.core.grid.slot_edge_ids` replaces a binary search of every
 segment slot among the inclusive segment offsets with a histogram of the
 offsets and a cumsum.  Both are exact integer computations, so the map
 must equal ``searchsorted(offsets, slot, side="right")`` element for
-element, and the strip builders must give bit-identical segments with
-either one.
+element, and the strip builder must give bit-identical segments with
+either one, for one layout (B=1) and for a batch.
 """
 
 import jax.numpy as jnp
@@ -70,29 +70,19 @@ def _long_edge_layouts(batch):
 
 
 @pytest.mark.parametrize("starved", [False, True], ids=["ample", "starved"])
-@pytest.mark.parametrize("batched", [False, True],
-                         ids=["single", "batched"])
-def test_strip_builders_unchanged_by_the_count(monkeypatch, batched,
-                                                starved):
-    n_strips, batch = 64, 3
-    layouts, edges, edge_valid = _long_edge_layouts(batch)
+@pytest.mark.parametrize("B", [1, 3], ids=["single", "batched"])
+def test_strip_builders_unchanged_by_the_count(monkeypatch, B, starved):
+    n_strips = 64
+    layouts, edges, edge_valid = _long_edge_layouts(B)
     ev = jnp.asarray(edge_valid)
     for axis in (0, 1):
-        if batched:
-            build = lambda: gridlib.build_strip_segments_batched(  # noqa: E731
-                jnp.asarray(layouts), jnp.asarray(edges), n_strips,
-                max_segments, axis=axis, edge_valid=ev)
-            first = gridlib.build_strip_segments_batched(
-                jnp.asarray(layouts), jnp.asarray(edges), n_strips, 1,
-                axis=axis, edge_valid=ev)
-            total = int(np.max(np.asarray(first.overflow))) + 1
-        else:
-            build = lambda: gridlib.build_strip_segments(  # noqa: E731
-                jnp.asarray(layouts[0]), jnp.asarray(edges), n_strips,
-                max_segments, axis=axis, edge_valid=ev)
-            total = int(gridlib.build_strip_segments(
-                jnp.asarray(layouts[0]), jnp.asarray(edges), n_strips, 1,
-                axis=axis, edge_valid=ev).overflow) + 1
+        build = lambda: gridlib.build_strip_segments_batched(  # noqa: E731
+            jnp.asarray(layouts), jnp.asarray(edges), n_strips,
+            max_segments, axis=axis, edge_valid=ev)
+        first = gridlib.build_strip_segments_batched(
+            jnp.asarray(layouts), jnp.asarray(edges), n_strips, 1,
+            axis=axis, edge_valid=ev)
+        total = int(np.max(np.asarray(first.overflow))) + 1
         assert total > 10 * n_strips        # long edges fill the strips
         max_segments = total // 2 if starved else total + 7
         got = build()
